@@ -1,52 +1,44 @@
-"""Real-compute benchmark: the execution bridge's committed claims.
+"""Real-compute benchmark: the served path on one TPU chip, checked.
 
-``BENCH_realcompute.json`` holds one replay of a scenario trace through
-both paths — the emulator's predicted stage latencies (from the
-measured-profile table) and the real Pallas execution wall times from
-the compile-cached ``serving.executor.RealExecutor`` — plus the
-compile-cache stats and the roofline/quota cross-checks from
-``launch/profile_kernels``.
+Runs ``launch.serve.serve_real`` for internlm2_1_8b at its published
+widths in bf16: scenario traffic through Gateway → ESG planner →
+``ClusterSim`` dispatch → the compile-cached ``RealExecutor`` → Pallas
+kernels.  The planner's predicted stage latencies (from the profile
+measured in the same process) are compared with the measured wall times
+of the served batches.  Its times are device times, so it refuses to run
+on anything but a TPU.
 
-Committed claims, all machine-independent ratios or identities (the
-absolute latencies in the file are informational — they depend on the
-host backend and are not guarded):
+Guards on the fresh document:
 
 1. **Zero recompiles after warmup** — the post-warmup compile-cache hit
    rate is exactly 1.0: batch-lattice bucketing means steady-state
    serving never sees a shape warmup didn't compile.
 2. **Calibration** — mean absolute predicted-vs-measured stage-latency
    error <= 15% across the executed (batch, quota) cells.
-3. **Provenance** — the planner ran against ``"measured"`` profiles
-   (threaded through Telemetry and the planner audit log).
+3. **Provenance** — the planner ran against ``"measured"`` profiles.
+4. **Lattice** — every executed bucket is on the measured lattice, and
+   less quota is never faster.
 
 Usage::
 
-    python benchmarks/realcompute_bench.py           # guard committed file
-    python benchmarks/realcompute_bench.py --smoke   # CI: fresh tiny run
-                                                     # + committed guards
-    python benchmarks/realcompute_bench.py --update  # regenerate baseline
+    python benchmarks/realcompute_bench.py [--n 48] [--out PATH]
 """
 from __future__ import annotations
 
 import argparse
-import json
 import pathlib
 import sys
 from typing import Optional, Sequence
 
-HERE = pathlib.Path(__file__).resolve().parent
-ROOT = HERE.parent
+ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
 
-BASELINE = ROOT / "BENCH_realcompute.json"
-
-# flagship configuration (committed — changing it invalidates baselines)
 ARCH = "internlm2_1_8b"
 N_REQUESTS = 48
 BATCHES = (1, 2, 4, 8)
 QUOTAS = (1.0, 0.5)
-PROMPT_LEN = 32
-GEN_LEN = 4
+PROMPT_LEN = 512
+GEN_LEN = 32
 REPS = 5
 SEED = 0
 
@@ -56,89 +48,67 @@ GUARDS = {
 }
 
 
-def run(n_requests: int = N_REQUESTS, batches: tuple = BATCHES,
-        quotas: tuple = QUOTAS, prompt_len: int = PROMPT_LEN,
-        gen_len: int = GEN_LEN, reps: int = REPS, seed: int = SEED,
-        out: Optional[str] = None) -> dict:
+def run(n_requests: int = N_REQUESTS, out: Optional[str] = None) -> dict:
+    from repro.configs.registry import get_config
     from repro.launch.serve import serve_real
-    return serve_real(arch=ARCH, n_requests=n_requests, scenario="mmpp",
-                      seed=seed, gen_len=gen_len, prompt_len=prompt_len,
-                      batches=batches, quotas=quotas, reps=reps,
-                      bench_out=out)
+    from repro.serving.executor import RealExecutor
+    ex = RealExecutor(get_config(ARCH), batch_lattice=BATCHES,
+                      quotas=QUOTAS, prompt_len=PROMPT_LEN, gen_len=GEN_LEN,
+                      seed=SEED)
+    doc = serve_real(ex, n_requests=n_requests, scenario="mmpp", seed=SEED,
+                     reps=REPS, bench_out=out)
+    ex.shutdown()
+    return doc
 
 
-def check_guards(doc: dict, fresh: bool = False) -> list[str]:
-    """Machine-independent checks on one benchmark document.
-
-    ``fresh=True`` relaxes the error guard: a tiny CI run measures
-    millisecond-scale cells whose wall-clock noise floor is above 15%,
-    so only the deterministic invariants (hit rate, provenance,
-    lattice) gate fresh runs — the error ratio gates the *committed*
-    document, which is produced at full scale.
-    """
+def check_guards(doc: dict) -> list[str]:
+    """Machine-independent checks on one benchmark document."""
     fails: list[str] = []
-    where = "fresh" if fresh else "baseline"
     ex = doc.get("executor", {})
     if ex.get("post_warmup_hit_rate") != GUARDS["post_warmup_hit_rate"]:
-        fails.append(f"{where}: post-warmup compile-cache hit rate "
+        fails.append(f"post-warmup compile-cache hit rate "
                      f"{ex.get('post_warmup_hit_rate')} != 1.0 "
                      f"(recompile after warmup)")
     if not ex.get("executed", 0):
-        fails.append(f"{where}: no batches executed")
-    if not fresh and doc.get("mean_abs_err", 1.0) > \
-            GUARDS["max_mean_abs_err"]:
-        fails.append(f"{where}: mean abs predicted-vs-measured error "
+        fails.append("no batches executed")
+    if doc.get("mean_abs_err", 1.0) > GUARDS["max_mean_abs_err"]:
+        fails.append(f"mean abs predicted-vs-measured error "
                      f"{doc.get('mean_abs_err'):.3f} > "
                      f"{GUARDS['max_mean_abs_err']}")
     prov = doc.get("telemetry", {}).get("profile_provenance", {})
     if prov.get(doc.get("arch")) != "measured":
-        fails.append(f"{where}: planner profile provenance is "
+        fails.append(f"planner profile provenance is "
                      f"{prov.get(doc.get('arch'))!r}, not 'measured'")
     lattice = set(doc.get("profile", {}).get("batch_lattice", []))
     for c in doc.get("cells", []):
         if c["batch"] not in lattice:
-            fails.append(f"{where}: executed bucket {c['batch']} is off "
-                         f"the measured lattice {sorted(lattice)}")
+            fails.append(f"executed bucket {c['batch']} is off the "
+                         f"measured lattice {sorted(lattice)}")
     qc = doc.get("quota_check", {})
     if qc.get("n_points") and qc.get("measured_exponent") is not None:
-        # sublinear sharing model sanity: the measured quota slowdown
-        # exponent must at least be positive (more quota never slower)
+        # more quota is never slower: the fitted slowdown exponent of
+        # the serialized-pass quota emulation must be positive
         if qc["measured_exponent"] <= 0:
-            fails.append(f"{where}: measured quota exponent "
+            fails.append(f"measured quota exponent "
                          f"{qc['measured_exponent']:.3f} <= 0")
     return fails
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: fresh tiny run (hit-rate guard) plus "
-                         "the committed baseline's ratio guards")
-    ap.add_argument("--update", action="store_true",
-                    help="rewrite the committed baseline (full scale)")
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--n", type=int, default=N_REQUESTS)
+    ap.add_argument("--out", default=None, metavar="PATH",
+                    help="write the benchmark document here")
     args = ap.parse_args(argv)
 
-    fails: list[str] = []
-    if args.smoke:
-        doc = run(n_requests=8, batches=(1, 2), quotas=(1.0,),
-                  prompt_len=16, gen_len=2, reps=1)
-        fails += check_guards(doc, fresh=True)
-        if BASELINE.exists():
-            fails += check_guards(json.loads(BASELINE.read_text()))
-        else:
-            print("[realcompute-bench] note: no committed baseline "
-                  "to guard")
-    elif args.update:
-        doc = run(n_requests=args.n, out=str(BASELINE))
-        fails += check_guards(doc)
-        print(f"[realcompute-bench] baseline written -> {BASELINE}")
-    else:
-        if not BASELINE.exists():
-            print(f"[realcompute-bench] missing {BASELINE}; run --update")
-            return 1
-        fails += check_guards(json.loads(BASELINE.read_text()))
-
+    from repro.launch.chip import device_info, use_compile_cache
+    use_compile_cache()
+    dev = device_info()
+    print(f"[realcompute-bench] device: {dev}")
+    if dev["platform"] != "tpu":
+        print("[realcompute-bench] needs a TPU: its times are device times")
+        return 1
+    fails = check_guards(run(n_requests=args.n, out=args.out))
     for f in fails:
         print(f"[realcompute-bench] GUARD FAIL: {f}")
     if not fails:

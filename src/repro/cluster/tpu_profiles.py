@@ -21,7 +21,9 @@ import numpy as np
 
 from repro.configs.registry import ModelConfig, get_config, ARCH_IDS
 from repro.core.profiles import FunctionProfile, ProfileTable
-from repro.launch.roofline import PEAK_FLOPS, HBM_BW, ICI_BW
+from repro.launch.roofline import V5E, peaks
+
+_V5E = peaks(V5E)
 
 DRYRUN_DIR = pathlib.Path(__file__).resolve().parents[3] / \
     "benchmarks" / "results" / "dryrun"
@@ -56,16 +58,16 @@ class TPUFunctionProfile(FunctionProfile):
         w_bytes = 2.0 * self._cfg.n_params          # bf16 weights read
         kv_bytes = 2.0 * 2 * self._cfg.n_layers * self._cfg.n_kv_heads * \
             self._cfg.d_head * self._spec.prompt_len * batch
-        t_mem = (w_bytes + kv_bytes) / (chips * HBM_BW)
-        t_flop = 2.0 * n * batch / (chips * PEAK_FLOPS)
+        t_mem = (w_bytes + kv_bytes) / (chips * _V5E.hbm_bw)
+        t_flop = 2.0 * n * batch / (chips * _V5E.flops)
         ici = 1.0 + 0.08 * np.log2(max(chips, 1))   # collective penalty
         return max(t_mem, t_flop) * ici * self._overhead * 1e3
 
     def _prefill_ms(self, batch: int, chips: int) -> float:
         n = self._cfg.n_active_params
         toks = batch * self._spec.prompt_len
-        t_flop = 2.0 * n * toks / (chips * PEAK_FLOPS)
-        t_mem = 2.0 * self._cfg.n_params / (chips * HBM_BW)
+        t_flop = 2.0 * n * toks / (chips * _V5E.flops)
+        t_mem = 2.0 * self._cfg.n_params / (chips * _V5E.hbm_bw)
         ici = 1.0 + 0.08 * np.log2(max(chips, 1))
         return max(t_flop, t_mem) * ici * self._overhead * 1e3
 

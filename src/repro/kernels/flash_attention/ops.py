@@ -1,9 +1,8 @@
-"""Jitted wrapper: model-layout adapter + CPU interpret fallback.
+"""Jitted wrapper: model-layout adapter for the flash attention kernel.
 
 The model passes (B, S, H, D) activations; the kernel wants heads-major.
-On non-TPU backends the kernel body runs under ``interpret=True`` (Python
-emulation — correctness only).  ``use_kernel=False`` falls back to the
-oracle entirely.
+On the CPU the kernel body runs under ``interpret=True`` (Python
+emulation — correctness only); see ``repro.kernels.interpret_mode``.
 """
 from __future__ import annotations
 
@@ -12,12 +11,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window",
@@ -30,7 +26,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, local_block=None,
     vT = jnp.swapaxes(v, 1, 2)
     out = flash_attention_fwd(qT, kT, vT, causal=causal, window=window,
                               local_block=local_block, q_offset=q_offset,
-                              interpret=not _on_tpu())
+                              interpret=interpret_mode())
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -1,17 +1,14 @@
-"""Jitted wrapper for flash_decode (interpret on non-TPU backends)."""
+"""Jitted wrappers for flash_decode (interpreted on the CPU)."""
 from __future__ import annotations
 
 import functools
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_decode.flash_decode import (
     flash_decode as _kernel, flash_decode_dynamic as _kernel_dyn)
 from repro.kernels.flash_decode.ref import decode_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit,
@@ -20,7 +17,7 @@ def flash_decode(q, k_cache, v_cache, *, t, window=None, local_block=None,
                  block_k=512):
     return _kernel(q, k_cache, v_cache, t=t, window=window,
                    local_block=local_block, block_k=block_k,
-                   interpret=not _on_tpu())
+                   interpret=interpret_mode())
 
 
 @functools.partial(jax.jit,
@@ -33,4 +30,4 @@ def flash_decode_at(q, k_cache, v_cache, t, *, window=None, local_block=None,
     ``t`` would recompile every token."""
     return _kernel_dyn(q, k_cache, v_cache, t, window=window,
                        local_block=local_block, block_k=block_k,
-                       interpret=not _on_tpu())
+                       interpret=interpret_mode())

@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.rwkv6.rwkv6 import wkv6_pallas
 from repro.kernels.rwkv6.ref import wkv6_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @jax.jit
@@ -30,6 +27,6 @@ def wkv6(r, k, v, lw, u, s0):
         # padded k rows are zero so they add nothing
         lwT = jnp.pad(lwT, ((0, 0), (0, 0), (0, pad), (0, 0)))
     y, s_fin = wkv6_pallas(*args, lwT, u, s0, chunk=chunk,
-                           interpret=not _on_tpu())
+                           interpret=interpret_mode())
     y = y[:, :, :t]
     return jnp.moveaxis(y, 1, 2), s_fin

@@ -36,10 +36,17 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     kc = k_ref[0, 0].astype(jnp.float32)
     vc = v_ref[0, 0].astype(jnp.float32)
     lwc = lw_ref[0, 0].astype(jnp.float32)      # (C, K) log-decay <= 0
-    u = u_ref[0].astype(jnp.float32)            # (K,)
+    u = u_ref[0].astype(jnp.float32)            # (1, K)
     s = state_ref[...]                          # (K, V)
 
-    cum = jnp.cumsum(lwc, axis=0)               # inclusive
+    # inclusive prefix sum over time as a lower-triangular matmul (the
+    # TPU kernel compiler has no cumsum)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >=
+           jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    cum = jax.lax.dot_general(tri.astype(jnp.float32), lwc,
+                              (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
     cum_prev = cum - lwc
     # inter-chunk: y += (r * exp(cum_prev)) @ S
     r_dec = rc * jnp.exp(cum_prev)
@@ -55,13 +62,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     y += jax.lax.dot_general(att, vc, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     # diagonal bonus: r_t (u . k_t) v_t
-    diag = jnp.sum(rc * u[None, :] * kc, axis=-1)         # (C,)
-    y += diag[:, None] * vc
+    diag = jnp.sum(rc * u * kc, axis=-1, keepdims=True)   # (C, 1)
+    y += diag * vc
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update: S' = diag(exp(cum_C)) S + sum_s exp(cum_C - cum_s) k_s v_s
     tail = cum[-1:, :] - cum                               # (C, K) <= 0
     k_dec = kc * jnp.exp(tail)
-    state_ref[...] = s * jnp.exp(cum[-1])[:, None] + jax.lax.dot_general(
+    # whole-chunk decay as a (K, 1) column: lw^T @ 1
+    cum_col = jax.lax.dot_general(lwc, jnp.ones((chunk, 1), jnp.float32),
+                                  (((0,), (0,)), ((), ())),
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  preferred_element_type=jnp.float32)
+    state_ref[...] = s * jnp.exp(cum_col) + jax.lax.dot_general(
         k_dec, vc, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -88,7 +100,9 @@ def wkv6_pallas(r, k, v, lw, u, s0, *, chunk: int = 32, interpret=False):
             pl.BlockSpec((1, 1, chunk, kd), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, chunk, vd), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, chunk, kd), lambda b_, h_, c: (b_, h_, c, 0)),
-            pl.BlockSpec((1, kd), lambda b_, h_, c: (h_, 0)),
+            # u as (H, 1, K): a block's last two dims must equal the
+            # array's (or be (8, 128)-aligned) on the TPU
+            pl.BlockSpec((1, 1, kd), lambda b_, h_, c: (h_, 0, 0)),
             pl.BlockSpec((1, 1, kd, vd), lambda b_, h_, c: (b_, h_, 0, 0)),
         ],
         out_specs=[
@@ -101,5 +115,5 @@ def wkv6_pallas(r, k, v, lw, u, s0, *, chunk: int = 32, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((kd, vd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, lw, u, s0)
+    )(r, k, v, lw, u.reshape(h, 1, kd), s0)
     return y, s_fin

@@ -79,7 +79,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         flops = float(hinfo["flops"])
         byts = float(hinfo["bytes"])
         terms = rf.roofline(cfg, shape, flops, byts,
-                            cinfo["total_wire_bytes"], n_chips)
+                            cinfo["total_wire_bytes"], n_chips,
+                            device_kind=rf.V5E)
         out.update({
             "status": "ok",
             "n_chips": n_chips,

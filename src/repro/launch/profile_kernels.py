@@ -8,11 +8,11 @@ across the (batch-bucket, quota) lattice and emits a
 
 Two cross-checks ride along in the artifact:
 
-* **Roofline** — each quota-1.0 cell is compared against the analytic
-  v5e lower bound from ``launch/roofline.py`` (``model_flops`` /
-  ``analytic_memory_bytes``).  On the CPU interpret backend the measured
-  time sits far above the TPU bound, so the fractions are *recorded*,
-  not asserted; on real hardware they become a sanity gate.
+* **Roofline** — on a TPU, each quota-1.0 cell is compared against the
+  analytic lower bound from ``launch/roofline.py`` (``model_flops`` /
+  ``analytic_memory_bytes``) with the published peaks of the device's
+  ``device_kind``.  Elsewhere there is no device to bound, and the check
+  reports "not measured".
 * **Quota exponent** — the fractional-quota slowdown measured from the
   serialized-pass emulation is fit to the profile model's power law and
   reported next to ``QUOTA_SLOWDOWN_EXP``.
@@ -20,7 +20,9 @@ Two cross-checks ride along in the artifact:
 CLI::
 
     PYTHONPATH=src python -m repro.launch.profile_kernels \
-        --arch internlm2_1_8b --out BENCH_profile.json --smoke
+        --arch internlm2_1_8b --out profile.json --smoke
+
+The model runs at its published widths in bf16.
 """
 from __future__ import annotations
 
@@ -28,35 +30,37 @@ import argparse
 import json
 import math
 
-from repro.configs.registry import ShapeSpec
+from repro.configs.registry import ShapeSpec, get_config
 from repro.core.profiles import QUOTA_SLOWDOWN_EXP
+from repro.launch.chip import device_info, use_compile_cache
 from repro.launch.roofline import model_flops, roofline
+
+NOT_MEASURED = "not measured"
 
 
 def roofline_check(executor, bucket: int, measured_ms: float,
-                   stage: str) -> dict:
-    """Compare one measured quota-1.0 cell against the analytic v5e
-    roofline bound for the same (reduced) config and shape."""
+                   stage: str, device: dict) -> dict:
+    """Compare one measured quota-1.0 cell against the analytic roofline
+    bound of ``device`` for the same config and shape."""
+    if stage == "decode":                  # per decode step
+        measured_ms = measured_ms / max(executor.gen_len, 1)
+    out = {"stage": stage, "batch": bucket, "measured_ms": measured_ms}
+    if device["platform"] != "tpu":
+        return {**out, "bound_ms": NOT_MEASURED,
+                "bound_fraction": NOT_MEASURED, "dominant": NOT_MEASURED}
     cfg = executor.cfg
     seq = executor.prompt_len if stage == "prefill" else 1
-    kind = "prefill" if stage == "prefill" else "decode"
     shape = ShapeSpec(f"profile_{stage}", seq_len=seq,
-                      global_batch=bucket, kind=kind)
+                      global_batch=bucket, kind=stage)
     terms = roofline(cfg, shape,
                      flops_per_device=model_flops(cfg, shape),
                      bytes_hlo_upper=0.0,   # analytic memory model only
-                     wire_bytes_per_device=0.0, n_chips=1)
+                     wire_bytes_per_device=0.0, n_chips=1,
+                     device_kind=device["kind"])
     bound_ms = terms.bound_s * 1e3
-    if stage == "decode":                  # per decode step
-        measured_ms = measured_ms / max(executor.gen_len, 1)
-    return {
-        "stage": stage,
-        "batch": bucket,
-        "bound_ms": bound_ms,
-        "measured_ms": measured_ms,
-        "bound_fraction": bound_ms / measured_ms if measured_ms else 0.0,
-        "dominant": terms.dominant,
-    }
+    return {**out, "bound_ms": bound_ms,
+            "bound_fraction": bound_ms / measured_ms if measured_ms else 0.0,
+            "dominant": terms.dominant}
 
 
 def quota_exponent(cells: list[dict]) -> dict:
@@ -88,10 +92,9 @@ def build_artifact(executor, reps: int = 3, cold_ms: float = 0.0,
     """Measure every (bucket, quota) lattice cell on an already-warmed
     :class:`RealExecutor` and assemble the ``repro.measured_profile.v1``
     artifact ``ProfileTable.from_measured`` consumes."""
-    import jax
-
     if not executor._warmed:
         executor.warmup()
+    device = device_info()
     cells, checks = [], []
     for bucket in executor.batch_lattice:
         for quota in executor.quotas:
@@ -109,16 +112,16 @@ def build_artifact(executor, reps: int = 3, cold_ms: float = 0.0,
                 f"{rec.decode_ms:.2f} decode)")
             if quota == 1.0:
                 checks.append(roofline_check(
-                    executor, bucket, rec.prefill_ms, "prefill"))
+                    executor, bucket, rec.prefill_ms, "prefill", device))
                 checks.append(roofline_check(
-                    executor, bucket, rec.decode_ms, "decode"))
-    backend = jax.default_backend()
+                    executor, bucket, rec.decode_ms, "decode", device))
     return {
         "schema": "repro.measured_profile.v1",
         "arch": executor.arch,
-        "reduced": True,
-        "backend": backend,
-        "interpret": backend != "tpu",
+        "reduced": executor.cfg != get_config(executor.arch),
+        "backend": device["platform"],
+        "device": device,
+        "interpret": executor.interpret,
         "prompt_len": executor.prompt_len,
         "gen_len": executor.gen_len,
         "batch_lattice": list(executor.batch_lattice),
@@ -138,8 +141,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--arch", default="internlm2_1_8b")
     ap.add_argument("--batches", type=int, nargs="+", default=[1, 2, 4, 8])
     ap.add_argument("--quotas", type=float, nargs="+", default=[1.0, 0.5])
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen-len", type=int, default=4)
+    ap.add_argument("--prompt-len", type=int, default=512)
+    ap.add_argument("--gen-len", type=int, default=32)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
@@ -151,7 +154,9 @@ def main(argv=None) -> dict:
 
     from repro.serving.executor import RealExecutor
 
-    ex = RealExecutor(args.arch, batch_lattice=tuple(args.batches),
+    use_compile_cache()
+    ex = RealExecutor(get_config(args.arch),
+                      batch_lattice=tuple(args.batches),
                       quotas=tuple(args.quotas),
                       prompt_len=args.prompt_len, gen_len=args.gen_len,
                       seed=args.seed)

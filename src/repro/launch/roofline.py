@@ -2,7 +2,10 @@
 
   compute term    = HLO_FLOPs_per_device / peak_FLOPs
   memory term     = HLO_bytes_per_device / HBM_bw
-  collective term = wire_bytes_per_device / link_bw
+  collective term = wire_bytes_per_device / ICI_bw
+
+Peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; a kind
+that is not in the table is an error, never a default.
 
 ``compiled.cost_analysis()`` reports per-device numbers (verified: an
 8-device sharded matmul reports ~global/8), so no further division by chips.
@@ -13,9 +16,32 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # B/s / chip
-ICI_BW = 50e9              # B/s / link (effective per-chip collective bw)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float               # bf16 FLOP/s per chip
+    hbm_bw: float              # HBM bytes/s per chip
+    ici_bw: float              # chip-to-chip interconnect bytes/s per chip
+
+
+V5E = "TPU v5 lite"            # jax device_kind of a TPU v5e chip
+
+# Published per-chip peaks.  Source: Google Cloud documentation, "TPU
+# v5e" (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s interchip interconnect.
+PEAKS = {
+    V5E: Peaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Published peaks of one chip of ``device_kind``; raises for a kind
+    the table does not know."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 
 @dataclasses.dataclass
@@ -137,14 +163,16 @@ def model_flops(cfg, shape) -> float:
 
 
 def roofline(cfg, shape, flops_per_device: float, bytes_hlo_upper: float,
-             wire_bytes_per_device: float, n_chips: int) -> RooflineTerms:
+             wire_bytes_per_device: float, n_chips: int,
+             device_kind: str) -> RooflineTerms:
+    pk = peaks(device_kind)
     mf = model_flops(cfg, shape)
     mem_bytes = min(analytic_memory_bytes(cfg, shape, n_chips),
                     bytes_hlo_upper if bytes_hlo_upper > 0 else float("inf"))
     return RooflineTerms(
-        compute_s=flops_per_device / PEAK_FLOPS,
-        memory_s=mem_bytes / HBM_BW,
-        collective_s=wire_bytes_per_device / ICI_BW,
+        compute_s=flops_per_device / pk.flops,
+        memory_s=mem_bytes / pk.hbm_bw,
+        collective_s=wire_bytes_per_device / pk.ici_bw,
         flops_per_device=flops_per_device,
         bytes_per_device=mem_bytes,
         wire_bytes_per_device=wire_bytes_per_device,

@@ -8,13 +8,13 @@ Two modes:
     (cluster/tpu_profiles).  This is the "assigned architectures as
     servable functions" configuration.
 
-  * ``--real``: actually serves a *reduced* model on this host through
-    the full control plane: scenario arrivals enter via the Gateway,
-    ESG_1Q plans batches against a *measured* profile table
+  * ``--real``: serves ``--arch`` at its published widths, in bf16,
+    through the full control plane: scenario arrivals enter via the
+    Gateway, ESG_1Q plans batches against a *measured* profile table
     (``launch/profile_kernels``), and every dispatched task is executed
     for real by the compile-cached ``serving.executor.RealExecutor``
     (Pallas prefill + scalar-prefetch decode).  ``--bench-out`` writes
-    the predicted-vs-measured comparison (BENCH_realcompute.json).
+    the predicted-vs-measured comparison.
 """
 from __future__ import annotations
 
@@ -146,40 +146,39 @@ def emulate(setting: str = "moderate-normal", n: int = 200, seed: int = 0,
     return s
 
 
-def serve_real(arch: str = "internlm2_1_8b", n_requests: int = 48,
-               scenario: str = "mmpp", autoscaler: str | None = None,
-               slo_mult: float = 8.0, seed: int = 0,
-               gen_len: int = 4, prompt_len: int = 32,
-               batches: tuple = (1, 2, 4, 8), quotas: tuple = (1.0, 0.5),
-               profile_path: str | None = None, reps: int = 2,
-               bench_out: str | None = None, log=print) -> dict:
+def serve_real(ex, n_requests: int = 48, scenario: str = "mmpp",
+               autoscaler: str | None = None, slo_mult: float = 8.0,
+               seed: int = 0, profile_path: str | None = None,
+               reps: int = 2, bench_out: str | None = None,
+               log=print) -> dict:
     """Real-compute serving through the full control plane.
 
-    Unlike the old bypass loop, this routes every request through the
-    same Gateway → autoscaler → ``ClusterSim`` dispatch path the
-    emulator uses: ESG_1Q plans batches against a *measured* profile
-    table, and each dispatched task is executed for real by the
-    compile-cached ``serving.executor.RealExecutor`` (actual Pallas
-    prefill + scalar-prefetch decode on a reduced ``arch``).
+    Every request goes through the same Gateway → autoscaler →
+    ``ClusterSim`` dispatch path the emulator uses: ESG_1Q plans batches
+    against a *measured* profile table, and each dispatched task is
+    executed for real by ``ex``, a compile-cached
+    ``serving.executor.RealExecutor`` (Pallas prefill + scalar-prefetch
+    decode on the config it was built with).  The caller owns ``ex``.
 
     The measured table comes from ``launch/profile_kernels`` — either
     built in-process (default) or loaded from ``profile_path``.  After
     the run, the per-cell measured wall times are compared against the
     planner's predicted stage latencies; the comparison (plus compile
-    cache stats and roofline cross-checks) is the
-    ``BENCH_realcompute.json`` payload (``bench_out``).
+    cache stats and roofline cross-checks) is the returned benchmark
+    document, also written to ``bench_out``.
     """
     import json
 
+    from repro.configs.registry import get_config
+    from repro.launch.chip import device_info
     from repro.launch.profile_kernels import build_artifact
     from repro.serving import Gateway, get_autoscaler, get_scenario
-    from repro.serving.executor import RealExecutor
 
-    ex = RealExecutor(arch, batch_lattice=tuple(batches),
-                      quotas=tuple(quotas), prompt_len=prompt_len,
-                      gen_len=gen_len, seed=seed)
-    log(f"[serve-real] warming {arch} (reduced): "
-        f"{len(ex.batch_lattice)} buckets x {len(ex.quotas)} quotas ...")
+    arch = ex.arch
+    reduced = ex.cfg != get_config(arch)
+    log(f"[serve-real] warming {arch} ({'reduced' if reduced else 'full'} "
+        f"width, bf16): {len(ex.batch_lattice)} buckets x "
+        f"{len(ex.quotas)} quotas ...")
     w = ex.warmup()
     log(f"[serve-real] warmup: {w['warmup_compiles']} compiles in "
         f"{w['warmup_s']:.1f}s ({w['cells']} cache cells)")
@@ -213,8 +212,7 @@ def serve_real(arch: str = "internlm2_1_8b", n_requests: int = 48,
                      count_overhead=False, autoscaler=scaler, executor=ex)
     gw = Gateway(sim)
     # pace arrivals to the measured service time: the stock scenario
-    # rates target zoo latencies (100s of ms) and a reduced arch at a
-    # few ms/batch would never queue — i.e. never leave batch 1
+    # rates target zoo latencies, which need not match this model's
     pace = max(table.fn.t1_ms / 2.0, 1.0)
     try:
         sc = get_scenario(scenario, app_names=[arch],
@@ -226,7 +224,6 @@ def serve_real(arch: str = "internlm2_1_8b", n_requests: int = 48,
     tel.scenario = scenario
     s = tel.summary()
     recs = ex.drain()
-    ex.shutdown()
 
     # predicted (planner profile) vs measured (device wall) per cell
     by_cell: dict[tuple, list] = {}
@@ -254,19 +251,17 @@ def serve_real(arch: str = "internlm2_1_8b", n_requests: int = 48,
     bench = {
         "schema": "repro.realcompute_bench.v1",
         "arch": arch,
-        "reduced": True,
+        "reduced": reduced,
         "scenario": scenario,
         "n_requests": n_requests,
         "seed": seed,
         "slo_mult": slo_mult,
-        "backend": artifact["backend"],
-        "interpret": artifact["interpret"],
-        "scale_note": "reduced arch on the host backend; latencies are "
-                      "machine-dependent, ratios (hit rate, abs_err, "
-                      "roofline fractions) are the regression surface",
+        "device": device_info(),
+        "interpret": ex.interpret,
         "profile": {k: artifact[k] for k in
                     ("batch_lattice", "quota_lattice", "prompt_len",
-                     "gen_len")},
+                     "gen_len", "cells")},
+        "warmup": w,
         "executor": stats,
         "cells": cells,
         "mean_abs_err": mean_abs_err,
@@ -281,7 +276,7 @@ def serve_real(arch: str = "internlm2_1_8b", n_requests: int = 48,
             "profile_provenance": s.get("profile_provenance", {}),
         },
     }
-    log(f"[serve-real] {arch}(reduced)/{scenario}: "
+    log(f"[serve-real] {arch}/{scenario}: "
         f"slo={s['slo_attainment']:.3f} executed={stats['executed']} "
         f"hit_rate={stats['post_warmup_hit_rate']} "
         f"mean_abs_err={mean_abs_err:.3f}")
@@ -338,9 +333,9 @@ def main():
                     help="(--real) measured batch lattice")
     ap.add_argument("--quotas", type=float, nargs="+", default=[1.0, 0.5],
                     help="(--real) measured fractional-quota lattice")
-    ap.add_argument("--gen-len", type=int, default=4,
+    ap.add_argument("--gen-len", type=int, default=32,
                     help="(--real) decode steps per request")
-    ap.add_argument("--prompt-len", type=int, default=32,
+    ap.add_argument("--prompt-len", type=int, default=512,
                     help="(--real) prompt length")
     ap.add_argument("--reps", type=int, default=2,
                     help="(--real) profiling reps per lattice cell")
@@ -349,17 +344,25 @@ def main():
                          "instead of profiling in-process")
     ap.add_argument("--bench-out", default=None, metavar="PATH",
                     help="(--real) write the predicted-vs-measured "
-                         "benchmark JSON (BENCH_realcompute.json) here")
+                         "benchmark JSON here")
     args = ap.parse_args()
     if args.real:
-        serve_real(arch=args.arch, n_requests=args.n if args.n else 48,
+        from repro.configs.registry import get_config
+        from repro.launch.chip import use_compile_cache
+        from repro.serving.executor import RealExecutor
+        use_compile_cache()
+        ex = RealExecutor(get_config(args.arch),
+                          batch_lattice=tuple(args.batches),
+                          quotas=tuple(args.quotas),
+                          prompt_len=args.prompt_len, gen_len=args.gen_len,
+                          seed=args.seed)
+        serve_real(ex, n_requests=args.n if args.n else 48,
                    scenario=args.scenario or "mmpp",
                    autoscaler=args.autoscaler, slo_mult=args.slo_mult
                    if args.slo_mult != 1.0 else 8.0, seed=args.seed,
-                   gen_len=args.gen_len, prompt_len=args.prompt_len,
-                   batches=tuple(args.batches), quotas=tuple(args.quotas),
                    profile_path=args.profile, reps=args.reps,
                    bench_out=args.bench_out)
+        ex.shutdown()
     else:
         emulate(args.setting, args.n, seed=args.seed,
                 scheduler=args.scheduler, scenario=args.scenario,

@@ -27,17 +27,6 @@ Array = jax.Array
 NEG_INF = -1e30
 
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax.shard_map(check_vma=) on new jax,
-    jax.experimental.shard_map.shard_map(check_rep=) on older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def constrain(x: Array, opts, pattern: tuple) -> Array:
     """with_sharding_constraint helper.  pattern entries: 'B' (batch/dp axes),
     'M' (model/TP axis), None.  No-op unless opts.shard_constraints."""
@@ -296,10 +285,10 @@ def explicit_tp_swiglu(x: Array, w1: Array, w2: Array, w3: Array,
 
     P = jax.sharding.PartitionSpec
     b = tuple(opts.dp_spec) if opts.dp_spec else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(b, None, None), P(fsdp, tp), P(fsdp, tp), P(tp, fsdp)),
-        out_specs=P(b, None, None))
+        out_specs=P(b, None, None), check_vma=False)
     return fn(x, w1, w2, w3)
 
 
@@ -321,17 +310,18 @@ def explicit_tp_matmul(x: Array, w: Array, opts, *, row: bool) -> Array:
             y = jnp.einsum("bsk,kn->bsn", x, w,
                            preferred_element_type=x.dtype)
             return jax.lax.psum(y, tp)
-        return shard_map(f, mesh=mesh,
-                     in_specs=(P(b, None, tp), P(tp, fsdp)),
-                     out_specs=P(b, None, None))(x, w)
+        return jax.shard_map(f, mesh=mesh,
+                             in_specs=(P(b, None, tp), P(tp, fsdp)),
+                             out_specs=P(b, None, None),
+                             check_vma=False)(x, w)
     # column: x replicated over tp; w: (K,N) P(fsdp, tp) -> out tp-sharded
     def f(x, w):
         w = jax.lax.all_gather(w, fsdp, axis=0, tiled=True)
         return jnp.einsum("bsk,kn->bsn", x, w,
                           preferred_element_type=x.dtype)
-    return shard_map(f, mesh=mesh,
-                     in_specs=(P(b, None, None), P(fsdp, tp)),
-                     out_specs=P(b, None, tp))(x, w)
+    return jax.shard_map(f, mesh=mesh,
+                         in_specs=(P(b, None, None), P(fsdp, tp)),
+                         out_specs=P(b, None, tp), check_vma=False)(x, w)
 
 
 def gelu_mlp(x: Array, w1: Array, b1: Array, w3: Array, b3: Array) -> Array:

@@ -342,10 +342,10 @@ def _seq_shard_decode(cfg, opts, q, k_new, v_new, k_cache, v_cache, t, kind):
         return out, kc, vc
 
     cspec = P(bspec, axis, None, None)
-    fn = L.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec), cspec, cspec, P()),
-        out_specs=(P(bspec), cspec, cspec))
+        out_specs=(P(bspec), cspec, cspec), check_vma=False)
     return fn(q, k_new, v_new, k_cache, v_cache, t)
 
 

@@ -6,7 +6,11 @@ prefill, scalar-prefetch flash_decode, WKV6 for SSM archs — all via
 ``ClusterSim`` dispatch path.  The emulator stays the timing/placement
 model; every dispatched task is additionally *executed for real* here,
 and the measured wall times validate the emulator's predictions
-(``BENCH_realcompute.json``).
+(``launch/serve.serve_real``).
+
+The executor serves whatever config its caller hands it, in bf16
+parameters and activations: the published widths on a chip, a
+``reduced()`` config only in CPU tests and the CPU rehearsal.
 
 Fast-path design, in order of importance:
 
@@ -45,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.registry import get_config, reduced
+from repro.configs.registry import ModelConfig
 from repro.gpu import SLICES_PER_VGPU
 from repro.models.model import RunOptions, get_model
 
@@ -69,20 +73,22 @@ class ExecRecord:
 
 
 class RealExecutor:
-    """Compile-cached batched real execution for one (reduced) arch."""
+    """Compile-cached batched real execution for one model config."""
 
-    def __init__(self, arch: str,
+    def __init__(self, cfg: ModelConfig,
                  batch_lattice: tuple = DEFAULT_BATCH_LATTICE,
                  quotas: tuple = DEFAULT_QUOTAS,
-                 prompt_len: int = 32, gen_len: int = 4,
-                 seed: int = 0, use_kernels: bool = True):
-        self.arch = arch
-        self.cfg = reduced(get_config(arch))
-        self.opts = RunOptions(use_kernels=use_kernels, remat="none",
-                               attn_chunk=64, param_dtype=jnp.float32,
-                               act_dtype=jnp.float32)
+                 prompt_len: int = 512, gen_len: int = 32,
+                 seed: int = 0):
+        self.arch = cfg.name
+        self.cfg = cfg
+        self.opts = RunOptions(use_kernels=True, remat="none",
+                               param_dtype=jnp.bfloat16,
+                               act_dtype=jnp.bfloat16)
         self.model = get_model(self.cfg, self.opts)
-        self.params = self.model.init(jax.random.PRNGKey(seed))
+        # built under jit: the bf16 weights are made on the device from
+        # the seed, with no float32 copy of the whole model
+        self.params = jax.jit(self.model.init)(jax.random.PRNGKey(seed))
         self.batch_lattice = tuple(sorted(batch_lattice))
         self.quotas = tuple(sorted(quotas, reverse=True))
         if 1.0 not in self.quotas:
@@ -105,6 +111,9 @@ class RealExecutor:
         # force a recompile; they still get their own cache entries so
         # the hit/miss accounting covers the full dispatch key.
         self._exe: dict[tuple, Any] = {}
+        # (stage, bucket) -> whether the compiled program calls a
+        # compiled Pallas kernel (False: interpreted on the CPU)
+        self.kernel_calls: dict[tuple, bool] = {}
         self.compiles = 0            # actual XLA compilations performed
         self.warmup_compiles = 0     # ... of which during warmup()
         self.cache_hits = 0          # submit()-time cache hits
@@ -130,14 +139,22 @@ class RealExecutor:
 
         prefill = jax.jit(prefill_fn).lower(self.params, toks).compile()
         self.compiles += 1
-        _, cache = prefill(self.params, toks)
-        nxt = jnp.zeros((bucket, 1), jnp.int32)
+        _, cache = jax.eval_shape(prefill_fn, self.params, toks)
+        nxt = jax.ShapeDtypeStruct((bucket, 1), jnp.int32)
         # donate the KV cache: the decode hot loop rewrites it in place
         decode = jax.jit(decode_fn, donate_argnums=(1,)).lower(
             self.params, cache, nxt).compile()
         self.compiles += 1
-        jax.block_until_ready(cache)
+        for stage, exe in (("prefill", prefill), ("decode", decode)):
+            self.kernel_calls[(stage, bucket)] = \
+                "tpu_custom_call" in exe.as_text()
         return prefill, decode
+
+    @property
+    def interpret(self) -> bool:
+        """Whether the kernels ran interpreted: no compiled program
+        calls a compiled Pallas kernel."""
+        return not any(self.kernel_calls.values())
 
     def _cell(self, stage: str, bucket: int, quota: float):
         """Cache lookup for one (arch, stage, bucket, quota) cell;
@@ -199,6 +216,25 @@ class RealExecutor:
             pre_ms += (t1 - t0) * 1e3
             dec_ms += (time.perf_counter() - t1) * 1e3
         return pre_ms, dec_ms
+
+    def trace(self, bucket: int) -> tuple[np.ndarray, np.ndarray]:
+        """Serve one bucket at full quota through the cached executables
+        and keep what a greedy client sees: the tokens fed (prompt, then
+        the generated tokens) and the float32 logits of every step,
+        prefill first.  Returns (tokens (B, prompt_len + gen_len),
+        logits (B, gen_len + 1, V))."""
+        prefill, _ = self._cell("prefill", bucket, 1.0)
+        decode, _ = self._cell("decode", bucket, 1.0)
+        toks = self._tokens[bucket]
+        logits, cache = prefill(self.params, toks)
+        fed, steps = [toks], [logits]
+        for _ in range(self.gen_len):
+            nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            logits, cache = decode(self.params, cache, nxt)
+            fed.append(nxt)
+            steps.append(logits)
+        return (np.asarray(jnp.concatenate(fed, axis=1)),
+                np.asarray(jnp.stack(steps, axis=1), np.float32))
 
     def bucket_of(self, n: int) -> int:
         for b in self.batch_lattice:
@@ -275,6 +311,7 @@ class RealExecutor:
             "quotas": list(self.quotas),
             "prompt_len": self.prompt_len,
             "gen_len": self.gen_len,
+            "interpret": self.interpret,
             "compiles": self.compiles,
             "warmup_compiles": self.warmup_compiles,
             "cache_hits": self.cache_hits,

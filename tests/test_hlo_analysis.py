@@ -82,8 +82,8 @@ def test_collectives_inside_while_multiplied():
     # shard_map over 1 device still emits the collective structure
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(devs[:1]), ("i",))
-    from repro.models.layers import shard_map
-    fm = jax.jit(shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P()))
+    fm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P(),
+                               check_vma=False))
     comp = fm.lower(jax.ShapeDtypeStruct((4, 8), jnp.float32)).compile()
     out = collectives.parse_collectives(comp.as_text(), 1)
     # the in-loop psum must appear with count 6 (or be optimised out on 1

@@ -3,17 +3,25 @@ compile-cache invariants, and emulator bit-identity with the executor
 attached.
 
 Kernel tests run the *ops-layer* wrappers (the exact entry points the
-serving executor and the model use, jit + layout adapters + CPU
-interpret fallback included) against the jnp references — the
+serving executor and the model use, jit + layout adapters + interpret
+mode on the CPU included) against the jnp references — the
 kernel-layer parity lives in tests/test_kernels.py.
 """
 from __future__ import annotations
+
+import json
+import os
+import pathlib
+import shutil
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+REPO = pathlib.Path(__file__).resolve().parents[1]
 ATOL = 2e-5          # float32 interpret mode: numerically tight
 RTOL = 2e-5
 WKV_TOL = 5e-3       # chunked scan reassociates the state recurrence
@@ -165,8 +173,10 @@ def test_zoo_profiles_report_zoo_provenance():
 
 @pytest.fixture(scope="module")
 def executor():
+    from repro.configs.registry import get_config, reduced
     from repro.serving.executor import RealExecutor
-    ex = RealExecutor("internlm2_1_8b", batch_lattice=(1, 2),
+    ex = RealExecutor(reduced(get_config("internlm2_1_8b")),
+                      batch_lattice=(1, 2),
                       quotas=(1.0, 0.5), prompt_len=8, gen_len=2, seed=0)
     ex.warmup()
     yield ex
@@ -252,3 +262,103 @@ def test_sim_digest_unchanged_by_attached_executor(executor):
         assert tel.summary()["profile_provenance"] == {arch: "measured"}
     executor.drain()
     assert digests[0] == digests[1]
+
+
+# ---- honest artifacts: device, interpret mode, peaks ----------------------
+
+def test_artifact_reads_interpret_and_reduced_off_the_run(executor):
+    from repro.launch.profile_kernels import NOT_MEASURED, build_artifact
+    art = build_artifact(executor, reps=1, log=lambda *_: None)
+    assert art["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert art["reduced"] is True                  # reduced() config
+    assert art["interpret"] is True                # no tpu_custom_call
+    assert set(executor.kernel_calls.values()) == {False}
+    # no published peaks for a CPU: the roofline bound is not measured
+    assert art["roofline"] and all(c["bound_ms"] == NOT_MEASURED
+                                   for c in art["roofline"])
+
+
+def test_kernels_interpret_only_on_the_cpu(monkeypatch):
+    from repro.kernels import interpret_mode
+    assert interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        interpret_mode()
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.configs.registry import ShapeSpec, get_config
+    from repro.launch.roofline import V5E, model_flops, peaks, roofline
+    pk = peaks(V5E)
+    assert (pk.flops, pk.hbm_bw, pk.ici_bw) == (197e12, 819e9, 200e9)
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")
+    cfg = get_config("internlm2_1_8b")
+    shape = ShapeSpec("decode", seq_len=1, global_batch=8, kind="decode")
+    terms = roofline(cfg, shape, model_flops(cfg, shape), 0.0, 2e9, 1,
+                     device_kind=V5E)
+    assert terms.collective_s == pytest.approx(2e9 / 200e9)
+    with pytest.raises(KeyError):
+        roofline(cfg, shape, 1.0, 0.0, 0.0, 1, device_kind="TPU v4")
+
+
+# ---- chip smoke and compile cache -----------------------------------------
+
+def _run_smoke(script, *args, env=None, cwd=None):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", **(env or {})}
+    return subprocess.run([sys.executable, str(script), *args], env=env,
+                          cwd=cwd, capture_output=True, text=True,
+                          timeout=600)
+
+
+def test_chip_smoke_refuses_a_non_tpu_platform(tmp_path):
+    r = _run_smoke(REPO / "chip_smoke.py",
+                   env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+    assert "needs 'tpu'" in r.stderr
+
+
+def test_chip_smoke_refuses_to_run_outside_the_repository(tmp_path):
+    script = tmp_path / "chip_smoke.py"
+    shutil.copy(REPO / "chip_smoke.py", script)
+    r = _run_smoke(script, cwd=tmp_path)
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+
+
+def test_chip_smoke_cpu_rehearsal_runs_the_reduced_config(tmp_path):
+    r = _run_smoke(REPO / "chip_smoke.py", "--cpu-rehearsal",
+                   env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert r.returncode == 0, r.stderr[-3000:]
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last == {"ok": False, "rehearsal": "cpu",
+                    "device": {"platform": "cpu", "kind": "cpu",
+                               "count": 1}}
+    assert "layers=2 d_model=64" in r.stdout          # reduced()
+    assert "post_warmup_hit_rate=1.0" in r.stdout
+    assert "top1_agreement=" in r.stdout
+
+
+def test_compile_cache_leaves_an_external_dir_to_jax(monkeypatch, tmp_path):
+    from repro.launch.chip import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_a_fixed_ignored_checkout_path(
+        monkeypatch):
+    from repro.launch.chip import use_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert path == str(REPO / ".jax_cache")
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().split()

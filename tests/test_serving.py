@@ -36,11 +36,16 @@ def test_emulated_zoo_serving_esg_hits():
 
 
 def test_real_serving_loop_smoke():
+    from repro.configs.registry import reduced
     from repro.launch.serve import serve_real
-    out = serve_real(arch="internlm2_1_8b", n_requests=6,
-                     batches=(1, 2), quotas=(1.0,), gen_len=2,
-                     prompt_len=16, reps=1, log=lambda *_: None)
+    from repro.serving.executor import RealExecutor
+    ex = RealExecutor(reduced(get_config("internlm2_1_8b")),
+                      batch_lattice=(1, 2), quotas=(1.0,), gen_len=2,
+                      prompt_len=16)
+    out = serve_real(ex, n_requests=6, reps=1, log=lambda *_: None)
+    ex.shutdown()
     assert out["n_requests"] == 6
+    assert out["reduced"] and out["interpret"]
     assert out["executor"]["executed"] > 0
     # the CI-asserted invariant: zero recompiles after warmup
     assert out["executor"]["post_warmup_hit_rate"] == 1.0
