@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no program ran on the chip
+(one minus the union of the device's module intervals over the window)."""
+
+
+def read(run):
+    if run.trace is None or not run.trace["window_s"]:
+        return None
+    return 1.0 - run.trace["busy_s"] / run.trace["window_s"]
