@@ -136,6 +136,7 @@ class Task:
     quota_slices: int = 0        # current compute quota (slices)
     exec_start_ms: float = 0.0   # start + residual restart penalty
     dispatch_ms: float = 0.0     # sim time the allocation was taken
+    queued: int = 0              # jobs in the stage queue when formed
     gen: int = 0                 # resize generation (stale-event guard)
     q_since: float = 0.0         # quota unchanged since (slice-ms account)
     # --- overlapped-swap accounting ---
@@ -1218,6 +1219,7 @@ class ClusterSim:
         func = app.func_of[stage]
         inv = self.invokers[inv_idx]
         q = self.queues[key]
+        queued = len(q)
         for _ in jobs:
             q.popleft()
         if not q:
@@ -1332,6 +1334,7 @@ class ClusterSim:
             task.quota_slices = slices
             task.exec_start_ms = exec_start
             task.dispatch_ms = self.now
+            task.queued = queued
             task.q_since = self.now
             task.penalty_ms = charged
             task.full_penalty_ms = full
@@ -1340,8 +1343,9 @@ class ClusterSim:
             task = Task(jobs, stage, func, cfg, inv_idx, start, end, cold,
                         cost, tid=tid, tier=tier, alloc_id=alloc.aid,
                         quota_slices=slices, exec_start_ms=exec_start,
-                        dispatch_ms=self.now, q_since=self.now,
-                        penalty_ms=charged, full_penalty_ms=full)
+                        dispatch_ms=self.now, queued=queued,
+                        q_since=self.now, penalty_ms=charged,
+                        full_penalty_ms=full)
         if self.retain == "full":
             self.tasks.append(task)
         if self.dispatch_feed is not None:

@@ -316,8 +316,7 @@ def main():
                     help="record per-request spans and write a "
                          "Perfetto-loadable Chrome-trace JSON here")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
-                    help="record windowed metrics and write JSON "
-                         "(or CSV if PATH ends in .csv) here")
+                    help="record windowed metrics and write JSON here")
     ap.add_argument("--audit-out", default=None, metavar="PATH",
                     help="record the planner decision audit log "
                          "and write JSONL here")

@@ -33,11 +33,11 @@ from repro.obs.audit import AuditLog, PlanRecord, RetryRecord, SkipRecord
 from repro.obs.calibrate import ProfileCalibrator
 from repro.obs.health import AlertRecord, HealthEngine
 from repro.obs.metrics import COUNTER, GAUGE, HIST, MetricsBus
-from repro.obs.tracer import SpanTracer
+from repro.obs.tracer import SpanTracer, host_span
 
 __all__ = ["Recorder", "NullRecorder", "NULL_RECORDER", "SpanTracer",
-           "MetricsBus", "AuditLog", "PlanRecord", "SkipRecord",
-           "RetryRecord", "ProfileCalibrator", "HealthEngine",
+           "host_span", "MetricsBus", "AuditLog", "PlanRecord",
+           "SkipRecord", "RetryRecord", "ProfileCalibrator", "HealthEngine",
            "AlertRecord"]
 
 
@@ -420,7 +420,7 @@ class Recorder:
             self.tracer.export_chrome_trace(trace_path)
         if metrics_path and self.metrics:
             out["metrics"] = metrics_path
-            self.metrics.export(metrics_path)
+            self.metrics.to_json(metrics_path)
         if audit_path and self.audit:
             out["audit"] = audit_path
             self.audit.export_jsonl(audit_path)

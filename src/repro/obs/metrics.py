@@ -13,15 +13,13 @@ milliseconds (default 1 s):
 The bus is fed *online* from emulator/gateway/device hooks through the
 flight recorder (``repro.obs.Recorder``) — no post-hoc scan of the run
 — which is what a live dashboard, the ROADMAP's sharded-replay RSS
-tracking and a Gym-style observation feed all need.  ``to_json`` /
-``to_csv`` export the whole bus for dashboards;
-``benchmarks/obs_overhead.py`` consumes it in CI.
+tracking and a Gym-style observation feed all need.  ``to_json``
+exports the whole bus for dashboards; ``benchmarks/obs_overhead.py``
+consumes it in CI.
 """
 from __future__ import annotations
 
-import csv
 import json
-import math
 from typing import Any
 
 COUNTER = "counter"
@@ -103,33 +101,3 @@ class MetricsBus:
             json.dump(doc, f, indent=1, sort_keys=True)
             f.write("\n")
         return doc
-
-    def to_csv(self, path: str) -> None:
-        """Long-format CSV: one row per (series, window).  Hist windows
-        fill count/sum/min/max, scalar kinds fill ``value``."""
-        with open(path, "w", newline="") as f:
-            w = csv.writer(f)
-            w.writerow(["series", "kind", "window_start_ms", "value",
-                        "count", "sum", "min", "max"])
-            for name in sorted(self.series):
-                kind, _ = self.series[name]
-                for t, v in self.points(name):
-                    if kind == HIST:
-                        w.writerow([name, kind, t, "", *v])
-                    else:
-                        w.writerow([name, kind, t, v, "", "", "", ""])
-
-    def export(self, path: str):
-        """Extension-dispatched export (.csv -> CSV, else JSON)."""
-        if str(path).endswith(".csv"):
-            return self.to_csv(path)
-        return self.to_json(path)
-
-    def rate_per_s(self, name: str) -> float:
-        """Mean per-second rate of a counter over its observed span."""
-        got = self.series.get(name)
-        if not got or not got[1]:
-            return 0.0
-        kind, d = got
-        span_ms = (max(d) - min(d) + 1) * self.window_ms
-        return self.total(name) / span_ms * 1e3 if span_ms > 0 else math.inf

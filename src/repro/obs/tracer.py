@@ -27,12 +27,18 @@ non-overlapping lanes (tids) so the file loads cleanly in
 
 Timestamps are *simulated* milliseconds, written as the microsecond
 ``ts``/``dur`` fields the format requires.
+
+:func:`host_span` is the one wall-clock span of the package: the real
+path's phases, timed on the host's ``perf_counter`` and mirrored onto the
+profiler's host plane, where they share a clock with the device's ops.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
+import time
 from typing import Any, Optional
 
 # pid layout: requests get 10000+uid, devices 100+idx — disjoint for any
@@ -41,6 +47,23 @@ REQUEST_PID_BASE = 10_000
 DEVICE_PID_BASE = 100
 
 _US = 1e3   # ms -> us
+
+
+@contextlib.contextmanager
+def host_span(spans: list, name: str, **meta):
+    """Time the body on the wall clock: append ``(name, t0_ns, t1_ns)``
+    (``time.perf_counter_ns``) to ``spans``.
+
+    The body also runs under a ``jax.profiler.TraceAnnotation`` of the
+    same name with ``meta`` as its metadata: a no-op with no profiler
+    session, and with one a host-plane event on the device trace's clock,
+    so the same span can be found on both clocks.  The clock reads sit
+    inside the annotation, so its cost falls outside the span."""
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation(name, **meta):
+        t0 = time.perf_counter_ns()
+        yield
+        spans.append((name, t0, time.perf_counter_ns()))
 
 
 @dataclasses.dataclass

@@ -13,10 +13,10 @@ roles, so any mix of artifacts can be passed in any order:
     lifecycle phase (request envelope, queue wait, exec; ``xfer``
     appears only when some start paid a restart penalty, so it is
     opt-in via ``required``);
-  * **metrics bus** (``.json`` with ``series``, or the long-format
-    ``.csv``) — known series kinds, well-formed points (scalar kinds
-    carry one value, hist windows carry count/sum/min/max with
-    min <= max <= sum consistency), strictly increasing window starts;
+  * **metrics bus** (``.json`` with ``series``) — known series kinds,
+    well-formed points (scalar kinds carry one value, hist windows
+    carry count/sum/min/max with min <= max <= sum consistency),
+    strictly increasing window starts;
   * **planner audit** (``.jsonl`` of plan/skip/retry records) —
     required fields per record type, numeric sanity, realized >= 0,
     retry actions in retry|resume|shed with attempt >= 1;
@@ -28,7 +28,6 @@ or ``file: line i:``) so a CI failure points at the exact artifact.
 """
 from __future__ import annotations
 
-import csv
 import json
 import sys
 from typing import Any, Iterable
@@ -147,44 +146,6 @@ def validate_metrics(doc: dict[str, Any], path: str = "metrics") -> int:
     return len(doc["series"])
 
 
-def validate_metrics_csv(path: str) -> int:
-    """Validate a MetricsBus long-format CSV export; returns the row
-    count.  Errors name the file and 1-based line number."""
-    header = ["series", "kind", "window_start_ms", "value",
-              "count", "sum", "min", "max"]
-    with open(path, newline="") as f:
-        rows = list(csv.reader(f))
-    if not rows or rows[0] != header:
-        raise ValueError(f"{path}: line 1: bad header {rows[0] if rows else []!r}, "
-                         f"expected {header!r}")
-    for i, row in enumerate(rows[1:], start=2):
-        if len(row) != len(header):
-            raise ValueError(f"{path}: line {i}: expected "
-                             f"{len(header)} columns, got {len(row)}")
-        name, kind, t, value, n, total, lo, hi = row
-        if kind not in _METRIC_KINDS:
-            raise ValueError(f"{path}: line {i}: series {name!r} has bad "
-                             f"kind {kind!r}")
-        try:
-            float(t)
-        except ValueError:
-            raise ValueError(f"{path}: line {i}: bad window_start_ms "
-                             f"{t!r}") from None
-        filled, blank = ((n, total, lo, hi), (value,)) if kind == "hist" \
-            else ((value,), (n, total, lo, hi))
-        if any(c == "" for c in filled) or any(c != "" for c in blank):
-            raise ValueError(
-                f"{path}: line {i}: {kind} row must fill "
-                f"{'count/sum/min/max' if kind == 'hist' else 'value'} "
-                f"and leave the rest empty, got {row!r}")
-        try:
-            [float(c) for c in filled]
-        except ValueError:
-            raise ValueError(f"{path}: line {i}: non-numeric cell in "
-                             f"{filled!r}") from None
-    return len(rows) - 1
-
-
 def _load_jsonl(path: str) -> list[dict[str, Any]]:
     records = []
     with open(path) as f:
@@ -277,9 +238,6 @@ def validate_health(records: list[dict[str, Any]],
 
 def _dispatch(path: str) -> str:
     """Validate one artifact, sniffing its format; returns a summary."""
-    if path.endswith(".csv"):
-        n = validate_metrics_csv(path)
-        return f"metrics-csv OK: {n} rows"
     if path.endswith(".jsonl"):
         records = _load_jsonl(path)
         types = {r.get("type") for r in records}
@@ -302,14 +260,14 @@ def _dispatch(path: str) -> str:
         n = validate_metrics(doc, path)
         return f"metrics OK: {n} series"
     raise ValueError(f"{path}: unrecognized artifact (want a Chrome "
-                     f"trace, a metrics export, or a .jsonl/.csv)")
+                     f"trace, a metrics export, or a .jsonl)")
 
 
 def main(argv: list[str] | None = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     if not argv:
         print("usage: python -m repro.obs.validate ARTIFACT... "
-              "(trace/metrics .json, metrics .csv, audit/health .jsonl)",
+              "(trace/metrics .json, audit/health .jsonl)",
               file=sys.stderr)
         return 2
     for path in argv:

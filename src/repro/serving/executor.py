@@ -37,6 +37,33 @@ model: the cell runs ``round(1/q)`` serialized passes, so the measured
 latency is what a container throttled to a 1/q device share observes.
 This is the measured counterpart of the profile model's
 ``QUOTA_SLOWDOWN_EXP`` (cross-checked by ``launch/profile_kernels.py``).
+
+Each served batch's :class:`ExecRecord` carries its wall-clock spans
+(``repro.obs.host_span``; ``(name, t0_ns, t1_ns)`` on
+``time.perf_counter_ns``), parented by the task id, with the task's
+request ids in ``uids``:
+
+* ``executor.queue`` — ``submit()`` to the worker taking the batch up:
+  time spent behind earlier batches in the single-worker FIFO.  It
+  crosses threads, so it is kept on the record only.
+* ``executor.run`` — the worker's whole batch, every pass; on the
+  profiler's host plane with ``tid``, ``n_jobs`` and ``bucket``.
+* Per pass, four children that tile the timed part of ``executor.run``:
+  ``executor.prefill.enqueue`` (prefill and the first argmax,
+  dispatched), ``executor.prefill.sync`` (waiting for them),
+  ``executor.decode.enqueue`` (the host loop that dispatches the
+  ``gen_len`` steps) and ``executor.decode.sync`` (waiting for the last).
+  ``prefill_ms``/``decode_ms`` are the sums of the first two and the
+  last two.  The runtime bounds the programs in flight, so a device-bound
+  loop runs a few steps ahead and then waits in each dispatch: a
+  ``decode.sync`` of several device steps means the device paces the
+  batch, one near zero means the host's dispatch does.
+
+The record also copies the dispatch counters of the task: its
+``dispatch_ms`` on the control plane's clock, each job's wait in the
+stage queue up to it (``waits_ms``), and how many jobs that queue held
+when the batch was formed (``queued``; more than ``n_jobs`` means the
+planner split the queue).
 """
 from __future__ import annotations
 
@@ -52,6 +79,7 @@ import numpy as np
 from repro.configs.registry import ModelConfig
 from repro.gpu import SLICES_PER_VGPU
 from repro.models.model import RunOptions, get_model
+from repro.obs import host_span
 
 DEFAULT_BATCH_LATTICE = (1, 2, 4, 8)
 DEFAULT_QUOTAS = (1.0, 0.5, 0.25)
@@ -70,6 +98,13 @@ class ExecRecord:
     prefill_ms: float           # prefill component
     decode_ms: float            # decode-loop component (gen_len steps)
     cache_hit: bool             # compile cache hit at submit time
+    # --- observability (empty for profiling runs) ---
+    uids: tuple = ()            # request ids of the task's jobs
+    # (name, t0_ns, t1_ns) on perf_counter_ns; see the module docstring
+    spans: list = dataclasses.field(default_factory=list)
+    dispatch_ms: float = 0.0    # control-plane time the task was dispatched
+    waits_ms: tuple = ()        # per job: dispatch_ms - its ready time
+    queued: int = 0             # jobs in the stage queue at dispatch
 
 
 class RealExecutor:
@@ -194,28 +229,34 @@ class RealExecutor:
                 "cells": len(self._exe)}
 
     # ---- execution --------------------------------------------------------
-    def _run(self, bucket: int, quota: float) -> tuple[float, float]:
+    def _run(self, bucket: int, quota: float
+             ) -> tuple[float, float, list]:
         """One real serve of a bucket at a quota: prefill + gen_len
         greedy decode steps, ``round(1/q)`` serialized passes.  Returns
-        (prefill_ms, decode_ms) wall components."""
+        (prefill_ms, decode_ms) wall components and the passes' phase
+        spans, four a pass."""
         prefill, _ = self._cell("prefill", bucket, quota)
         decode, _ = self._cell("decode", bucket, quota)
         passes = max(int(round(1.0 / quota)), 1)
         toks = self._tokens[bucket]
-        pre_ms = dec_ms = 0.0
+        spans: list = []
         for _ in range(passes):
-            t0 = time.perf_counter()
-            logits, cache = prefill(self.params, toks)
-            nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            jax.block_until_ready(nxt)
-            t1 = time.perf_counter()
-            for _ in range(self.gen_len):
-                logits, cache = decode(self.params, cache, nxt)
+            with host_span(spans, "executor.prefill.enqueue"):
+                logits, cache = prefill(self.params, toks)
                 nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            jax.block_until_ready(nxt)
-            pre_ms += (t1 - t0) * 1e3
-            dec_ms += (time.perf_counter() - t1) * 1e3
-        return pre_ms, dec_ms
+            with host_span(spans, "executor.prefill.sync"):
+                jax.block_until_ready(nxt)
+            with host_span(spans, "executor.decode.enqueue"):
+                for _ in range(self.gen_len):
+                    logits, cache = decode(self.params, cache, nxt)
+                    nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            with host_span(spans, "executor.decode.sync"):
+                jax.block_until_ready(nxt)
+        pre_ms = sum(t1 - t0 for n, t0, t1 in spans
+                     if n.startswith("executor.prefill.")) / 1e6
+        dec_ms = sum(t1 - t0 for n, t0, t1 in spans
+                     if n.startswith("executor.decode.")) / 1e6
+        return pre_ms, dec_ms, spans
 
     def trace(self, bucket: int) -> tuple[np.ndarray, np.ndarray]:
         """Serve one bucket at full quota through the cached executables
@@ -252,6 +293,7 @@ class RealExecutor:
     def submit(self, task) -> Future:
         """ClusterSim._dispatch hook: execute the dispatched task for
         real, asynchronously.  Never blocks the emulator thread."""
+        t_submit = time.perf_counter_ns()
         n_jobs = len(task.jobs)
         bucket = self.bucket_of(max(n_jobs, 1))
         quota = self.quota_of(task)
@@ -265,13 +307,23 @@ class RealExecutor:
         else:
             self.cache_misses += 1
         tid, func, stage = task.tid, task.func, task.stage
+        # copied now: the emulator may reuse the task and its jobs
+        dispatch_ms, queued = task.dispatch_ms, task.queued
+        uids = tuple(j.inst.uid for j in task.jobs)
+        waits = tuple(dispatch_ms - j.ready_ms for j in task.jobs)
 
         def work() -> ExecRecord:
-            pre, dec = self._run(bucket, quota)
+            spans = [("executor.queue", t_submit, time.perf_counter_ns())]
+            with host_span(spans, "executor.run", tid=tid, n_jobs=n_jobs,
+                           bucket=bucket):
+                pre, dec, phases = self._run(bucket, quota)
+            spans += phases
             rec = ExecRecord(tid=tid, func=func, stage=stage,
                              n_jobs=n_jobs, bucket=bucket, quota=quota,
                              wall_ms=pre + dec, prefill_ms=pre,
-                             decode_ms=dec, cache_hit=hit)
+                             decode_ms=dec, cache_hit=hit, uids=uids,
+                             spans=spans, dispatch_ms=dispatch_ms,
+                             waits_ms=waits, queued=queued)
             self.records.append(rec)
             return rec
 

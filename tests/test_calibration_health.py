@@ -41,7 +41,7 @@ from repro.obs.health import (ALERT_KINDS, CAL_DRIFT, CLEARED, COLD_SPIKE,
                               FIRING, PREFETCH_WASTE, QUEUE_BUILDUP,
                               SLO_BURN)
 from repro.obs.validate import (main as validate_main, validate_audit,
-                                validate_health, validate_metrics_csv)
+                                validate_health, validate_metrics)
 from repro.serving import Gateway, get_autoscaler, get_scenario
 from repro.serving.autoscaler import AUTOSCALERS
 from repro.serving.traces import SCENARIOS
@@ -520,18 +520,27 @@ def test_audit_per_stage_quantiles_gate_on_sample_count():
 # validator extensions: offending file and record are always named
 # ---------------------------------------------------------------------------
 def test_validate_metrics_csv_roundtrip_and_errors(tmp_path, tables):
+    """The metrics bus exports JSON only: the export round-trips through
+    the validator, a corrupted point is named by file, series and point,
+    and a long-format CSV is refused as no known artifact."""
     rec = Recorder(trace=False)
     _run(tables, "mmpp", recorder=rec)
-    good = tmp_path / "metrics.csv"
-    rec.metrics.to_csv(str(good))
-    assert validate_metrics_csv(str(good)) > 0
-    lines = good.read_text().splitlines()
-    bad = tmp_path / "corrupt.csv"
-    bad.write_text("\n".join([lines[0], lines[1].rsplit(",", 1)[0]
-                              + ",not_a_number"] + lines[2:]) + "\n")
+    good = tmp_path / "metrics.json"
+    rec.export(metrics_path=str(good))
+    doc = json.loads(good.read_text())
+    assert validate_metrics(doc, str(good)) == len(rec.metrics.series) > 0
+    name = next(iter(doc["series"]))
+    doc["series"][name]["points"][0][-1] = "not_a_number"
+    bad = tmp_path / "corrupt.json"
+    bad.write_text(json.dumps(doc))
     with pytest.raises(ValueError) as ei:
-        validate_metrics_csv(str(bad))
-    assert "corrupt.csv" in str(ei.value) and "line 2" in str(ei.value)
+        validate_main([str(bad)])
+    assert "corrupt.json" in str(ei.value) and "point 0" in str(ei.value)
+    assert repr(name) in str(ei.value)
+    old = tmp_path / "metrics.csv"
+    old.write_text("series,kind,window_start_ms,value,count,sum,min,max\n")
+    with pytest.raises(ValueError):
+        validate_main([str(old)])
 
 
 def test_validate_audit_names_offending_record(tmp_path, tables):
@@ -622,9 +631,7 @@ def test_validate_cli_dispatches_all_artifacts(tmp_path, tables):
     metrics = tmp_path / "metrics.json"
     audit = tmp_path / "audit.jsonl"
     health = tmp_path / "health.jsonl"
-    csv = tmp_path / "metrics.csv"
     rec.export(str(trace), str(metrics), str(audit),
                health_path=str(health))
-    rec.metrics.to_csv(str(csv))
     assert validate_main([str(trace), str(metrics), str(audit),
-                          str(health), str(csv)]) == 0
+                          str(health)]) == 0

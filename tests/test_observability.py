@@ -269,7 +269,6 @@ def test_metrics_bus_windows_and_kinds(tmp_path):
     assert m.total("c") == 4.0
     assert m.points("g") == [(0.0, 7.0)]
     assert m.points("h") == [(0.0, [2, 12.0, 3.0, 9.0])]
-    assert m.rate_per_s("c") == pytest.approx(4.0 / 0.2)
     with pytest.raises(ValueError, match="is a counter"):
         m.gauge("c", 0.0, 1.0)
     with pytest.raises(ValueError, match="not a counter"):
@@ -278,20 +277,24 @@ def test_metrics_bus_windows_and_kinds(tmp_path):
         MetricsBus(window_ms=0.0)
     doc = m.to_json(str(tmp_path / "m.json"))
     assert validate_metrics(doc) == 3
-    m.to_csv(str(tmp_path / "m.csv"))
-    rows = (tmp_path / "m.csv").read_text().splitlines()
-    assert rows[0].startswith("series,kind,window_start_ms")
-    assert len(rows) == 1 + 2 + 1 + 1    # header + c windows + g + h
+    assert json.loads((tmp_path / "m.json").read_text()) == doc
+    series = doc["series"]
+    assert series["c"] == {"kind": "counter",
+                           "points": [[0.0, 3.0], [100.0, 1.0]]}
+    assert series["g"] == {"kind": "gauge", "points": [[0.0, 7.0]]}
+    assert series["h"] == {"kind": "hist",
+                           "points": [[0.0, 2, 12.0, 3.0, 9.0]]}
 
 
 def test_recorder_exports_all_three_artifacts(tables, tmp_path):
     rec = Recorder()
     _run(tables, "mmpp", recorder=rec)
-    out = rec.export(str(tmp_path / "t.json"), str(tmp_path / "m.csv"),
+    out = rec.export(str(tmp_path / "t.json"), str(tmp_path / "m.json"),
                      str(tmp_path / "a.jsonl"))
     assert set(out) == {"trace", "metrics", "audit"}
     validate_trace(json.loads((tmp_path / "t.json").read_text()))
-    assert (tmp_path / "m.csv").read_text().startswith("series,")
+    assert validate_metrics(json.loads((tmp_path / "m.json").read_text())) \
+        == len(rec.metrics.series)
     assert (tmp_path / "a.jsonl").read_text().strip()
     # metrics carry the headline serving series
     names = set(rec.metrics.series)

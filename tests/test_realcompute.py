@@ -184,14 +184,22 @@ def executor():
 
 
 class _FakeTask:
+    """A dispatched task as ``ClusterSim._dispatch`` hands it over: jobs
+    made ready 10, 20, ... ms before the dispatch at 100 ms, two more
+    jobs left behind in the stage queue."""
     _next = iter(range(10_000))
 
     def __init__(self, n_jobs, slices=4):
+        from repro.cluster.emulator import AppInstance, Job
         from repro.core.profiles import Config
         self.tid = next(self._next)
         self.func = "internlm2_1_8b"
         self.stage = "0:internlm2_1_8b"
-        self.jobs = [None] * n_jobs
+        self.dispatch_ms = 100.0
+        self.jobs = [Job(AppInstance(None, 1000 * self.tid + i, 0.0, 1e3),
+                         self.stage, self.dispatch_ms - 10.0 * (i + 1))
+                     for i in range(n_jobs)]
+        self.queued = n_jobs + 2
         self.config = Config(n_jobs, 1, 1)
         self.quota_slices = slices
 
@@ -204,6 +212,37 @@ def test_executor_zero_recompiles_after_warmup(executor):
     assert executor.compiles == compiles_before      # zero new XLA compiles
     assert executor.cache_misses == 0
     assert executor.stats()["post_warmup_hit_rate"] == 1.0
+
+
+PHASES = ("executor.prefill.enqueue", "executor.prefill.sync",
+          "executor.decode.enqueue", "executor.decode.sync")
+
+
+@pytest.mark.parametrize("n,slices", [(2, 4), (1, 2)])
+def test_executor_record_holds_spans_and_dispatch_counters(executor, n,
+                                                           slices):
+    task = _FakeTask(n, slices)
+    rec = executor.submit(task).result()
+    passes = 1 if slices == 4 else 2
+    names = [s[0] for s in rec.spans]
+    assert names == ["executor.queue", "executor.run"] + list(PHASES) * passes
+    (_, q0, q1), (_, r0, r1) = rec.spans[:2]
+    children = rec.spans[2:]
+    assert q0 <= q1 <= r0                      # queued, then taken up
+    # the children lie inside the run, in order, without overlap
+    edges = [t for _, t0, t1 in children for t in (t0, t1)]
+    assert edges == sorted(edges) and r0 <= edges[0] and edges[-1] <= r1
+    # ... and are the timed phases: their sums are the record's times
+    ms = lambda pre: sum(t1 - t0 for n_, t0, t1 in children
+                         if n_.startswith(pre)) / 1e6
+    assert ms("executor.") == pytest.approx(rec.wall_ms, abs=1.0)
+    assert ms("executor.prefill.") == pytest.approx(rec.prefill_ms, abs=1e-6)
+    assert ms("executor.decode.") == pytest.approx(rec.decode_ms, abs=1e-6)
+    assert rec.tid == task.tid
+    assert rec.uids == tuple(j.inst.uid for j in task.jobs)
+    assert rec.dispatch_ms == task.dispatch_ms == 100.0
+    assert rec.waits_ms == tuple(10.0 * (i + 1) for i in range(n))
+    assert rec.queued == task.queued == n + 2
 
 
 def test_executor_bucketing_and_quota_snap(executor):
