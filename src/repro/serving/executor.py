@@ -53,11 +53,14 @@ request ids in ``uids``:
   dispatched), ``executor.prefill.sync`` (waiting for them),
   ``executor.decode.enqueue`` (the host loop that dispatches the
   ``gen_len`` steps) and ``executor.decode.sync`` (waiting for the last).
-  ``prefill_ms``/``decode_ms`` are the sums of the first two and the
-  last two.  The runtime bounds the programs in flight, so a device-bound
-  loop runs a few steps ahead and then waits in each dispatch: a
-  ``decode.sync`` of several device steps means the device paces the
-  batch, one near zero means the host's dispatch does.
+  ``prefill_ms`` runs from a pass's prefill dispatch to the end of its
+  prefill sync, ``decode_ms`` from there to the end of its decode sync.
+* ``executor.decode.enqueue.head``, inside ``executor.decode.enqueue``:
+  the dispatch of a pass's first ``DECODE_HEAD_STEPS`` steps.  The
+  runtime bounds the programs in flight, so past about ten steps a
+  device-bound loop waits in each dispatch and ``decode.enqueue`` reads
+  the device's pace; the head starts on an idle device and stays under
+  that bound, so it reads the host's own cost of dispatching a step.
 
 The record also copies the dispatch counters of the task: its
 ``dispatch_ms`` on the control plane's clock, each job's wait in the
@@ -83,6 +86,10 @@ from repro.obs import host_span
 
 DEFAULT_BATCH_LATTICE = (1, 2, 4, 8)
 DEFAULT_QUOTAS = (1.0, 0.5, 0.25)
+# decode steps a pass dispatches under ``executor.decode.enqueue.head``:
+# fewer than the runtime keeps in flight, so none of them waits for the
+# device (see the module docstring)
+DECODE_HEAD_STEPS = 8
 
 
 @dataclasses.dataclass
@@ -105,6 +112,21 @@ class ExecRecord:
     dispatch_ms: float = 0.0    # control-plane time the task was dispatched
     waits_ms: tuple = ()        # per job: dispatch_ms - its ready time
     queued: int = 0             # jobs in the stage queue at dispatch
+
+
+def phase_ms(spans: list) -> tuple[float, float]:
+    """(prefill_ms, decode_ms) of a batch from its passes' phase spans:
+    each pass's prefill from its dispatch to the end of its sync, and its
+    decode from there to the end of the decode sync."""
+    pre = dec = 0
+    for name, t0, t1 in spans:
+        if name == "executor.prefill.enqueue":
+            start = t0
+        elif name == "executor.prefill.sync":
+            pre, mid = pre + t1 - start, t1
+        elif name == "executor.decode.sync":
+            dec += t1 - mid
+    return pre / 1e6, dec / 1e6
 
 
 class RealExecutor:
@@ -229,16 +251,16 @@ class RealExecutor:
                 "cells": len(self._exe)}
 
     # ---- execution --------------------------------------------------------
-    def _run(self, bucket: int, quota: float
-             ) -> tuple[float, float, list]:
+    def _run(self, bucket: int, quota: float) -> list:
         """One real serve of a bucket at a quota: prefill + gen_len
         greedy decode steps, ``round(1/q)`` serialized passes.  Returns
-        (prefill_ms, decode_ms) wall components and the passes' phase
-        spans, four a pass."""
+        the passes' phase spans, in the order they end (see the module
+        docstring); :func:`phase_ms` turns them into wall times."""
         prefill, _ = self._cell("prefill", bucket, quota)
         decode, _ = self._cell("decode", bucket, quota)
         passes = max(int(round(1.0 / quota)), 1)
         toks = self._tokens[bucket]
+        head = min(DECODE_HEAD_STEPS, self.gen_len)
         spans: list = []
         for _ in range(passes):
             with host_span(spans, "executor.prefill.enqueue"):
@@ -247,16 +269,17 @@ class RealExecutor:
             with host_span(spans, "executor.prefill.sync"):
                 jax.block_until_ready(nxt)
             with host_span(spans, "executor.decode.enqueue"):
-                for _ in range(self.gen_len):
+                with host_span(spans, "executor.decode.enqueue.head"):
+                    for _ in range(head):
+                        logits, cache = decode(self.params, cache, nxt)
+                        nxt = jnp.argmax(logits, -1)[:, None].astype(
+                            jnp.int32)
+                for _ in range(self.gen_len - head):
                     logits, cache = decode(self.params, cache, nxt)
                     nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
             with host_span(spans, "executor.decode.sync"):
                 jax.block_until_ready(nxt)
-        pre_ms = sum(t1 - t0 for n, t0, t1 in spans
-                     if n.startswith("executor.prefill.")) / 1e6
-        dec_ms = sum(t1 - t0 for n, t0, t1 in spans
-                     if n.startswith("executor.decode.")) / 1e6
-        return pre_ms, dec_ms, spans
+        return spans
 
     def trace(self, bucket: int) -> tuple[np.ndarray, np.ndarray]:
         """Serve one bucket at full quota through the cached executables
@@ -316,8 +339,9 @@ class RealExecutor:
             spans = [("executor.queue", t_submit, time.perf_counter_ns())]
             with host_span(spans, "executor.run", tid=tid, n_jobs=n_jobs,
                            bucket=bucket):
-                pre, dec, phases = self._run(bucket, quota)
+                phases = self._run(bucket, quota)
             spans += phases
+            pre, dec = phase_ms(phases)
             rec = ExecRecord(tid=tid, func=func, stage=stage,
                              n_jobs=n_jobs, bucket=bucket, quota=quota,
                              wall_ms=pre + dec, prefill_ms=pre,
@@ -338,7 +362,7 @@ class RealExecutor:
         Wall-clock noise on a shared host is one-sided (runs only ever
         get slower), so the minimum is the reproducible statistic — a
         median of few reps swings ~10% run to run at ms-scale cells."""
-        runs = [self._run(bucket, quota) for _ in range(reps)]
+        runs = [phase_ms(self._run(bucket, quota)) for _ in range(reps)]
         pre = float(np.min([r[0] for r in runs]))
         dec = float(np.min([r[1] for r in runs]))
         return ExecRecord(tid=-1, func=self.arch, stage="", n_jobs=bucket,

@@ -519,7 +519,7 @@ def test_audit_per_stage_quantiles_gate_on_sample_count():
 # ---------------------------------------------------------------------------
 # validator extensions: offending file and record are always named
 # ---------------------------------------------------------------------------
-def test_validate_metrics_csv_roundtrip_and_errors(tmp_path, tables):
+def test_validate_metrics_json_roundtrip_and_errors(tmp_path, tables):
     """The metrics bus exports JSON only: the export round-trips through
     the validator, a corrupted point is named by file, series and point,
     and a long-format CSV is refused as no known artifact."""

@@ -31,17 +31,20 @@ MIXES = {CHAT: dict(prompt_len=16, gen_len=32, rate_rps=4.0,
 MS = 1_000_000      # ns
 
 
-def _record(queue_ms, enqueue_ms, waits, queued):
+def _record(queue_ms, head_ms, waits, queued):
     spans = [("executor.queue", 0, int(queue_ms * MS)),
              ("executor.run", 10 * MS, 90 * MS)]
     t = 10 * MS
-    for e in enqueue_ms:     # one pass per decode.enqueue span
-        for name, dur in (("executor.prefill.enqueue", MS),
-                          ("executor.prefill.sync", MS),
-                          ("executor.decode.enqueue", int(e * MS)),
-                          ("executor.decode.sync", MS)):
-            spans.append((name, t, t + dur))
-            t += dur
+    for h in head_ms:        # one pass per decode.enqueue.head span
+        spans += [("executor.prefill.enqueue", t, t + MS),
+                  ("executor.prefill.sync", t + MS, t + 2 * MS),
+                  ("executor.decode.enqueue.head", t + 2 * MS,
+                   t + 2 * MS + int(h * MS)),
+                  # the whole loop: read by no metric (it waits on the
+                  # device once the runtime's bound on programs is reached)
+                  ("executor.decode.enqueue", t + 2 * MS, t + 20 * MS),
+                  ("executor.decode.sync", t + 20 * MS, t + 21 * MS)]
+        t += 21 * MS
     return ExecRecord(tid=0, func="f", stage="s", n_jobs=len(waits),
                       bucket=len(waits), quota=1.0, wall_ms=1.0,
                       prefill_ms=0.5, decode_ms=0.5, cache_hit=True,
@@ -50,9 +53,10 @@ def _record(queue_ms, enqueue_ms, waits, queued):
                       queued=queued)
 
 
-def _run(batches):
-    return pb_cell.Run(cell={"name": CHAT}, config={}, mix={"gen_len": 4},
-                       seconds=1.0, requests=[], batches=batches, plan_s=[])
+def _run(batches, gen_len=16):
+    return pb_cell.Run(cell={"name": CHAT}, config={},
+                       mix={"gen_len": gen_len}, seconds=1.0, requests=[],
+                       batches=batches, plan_s=[])
 
 
 def _batch(record):
@@ -64,16 +68,18 @@ SERVED = [_batch(_record(2.0, [1.0, 1.0], [10.0, 30.0], 5)),
           _batch(None)]                 # failed: read by no metric
 
 
-@pytest.mark.parametrize("name,want", [
-    ("exec_queue_ms.lat", (2.0 + 4.0) / 2),
-    ("dispatch_wait_ms.lat", (10.0 + 30.0 + 20.0) / 3),
-    ("queued_at_dispatch.cost", (5 + 1) / 2),
-    ("decode_host_ms.lat", (1.0 + 1.0 + 2.0) / (3 * 4)),
-    ("decode_host_ms.tput", (1.0 + 1.0 + 2.0) / (3 * 4)),
+# the head times DECODE_HEAD_STEPS (8) steps a pass, or gen_len if fewer
+@pytest.mark.parametrize("name,gen_len,want", [
+    ("exec_queue_ms.lat", 16, (2.0 + 4.0) / 2),
+    ("dispatch_wait_ms.lat", 16, (10.0 + 30.0 + 20.0) / 3),
+    ("queued_at_dispatch.cost", 16, (5 + 1) / 2),
+    ("decode_host_ms.lat", 16, (1.0 + 1.0 + 2.0) / (3 * 8)),
+    ("decode_host_ms.tput", 16, (1.0 + 1.0 + 2.0) / (3 * 8)),
+    ("decode_host_ms.lat", 4, (1.0 + 1.0 + 2.0) / (3 * 4)),
 ])
-def test_reader_reads_the_records(name, want):
+def test_reader_reads_the_records(name, gen_len, want):
     read = pb_cell.reader(name)
-    assert read(_run(SERVED)) == pytest.approx(want)
+    assert read(_run(SERVED, gen_len)) == pytest.approx(want)
     # nothing served, or records that carry no spans or counters (a
     # program without them): nothing to read, and no error
     assert read(_run([])) is None

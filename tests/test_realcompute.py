@@ -216,6 +216,7 @@ def test_executor_zero_recompiles_after_warmup(executor):
 
 PHASES = ("executor.prefill.enqueue", "executor.prefill.sync",
           "executor.decode.enqueue", "executor.decode.sync")
+HEAD = "executor.decode.enqueue.head"
 
 
 @pytest.mark.parametrize("n,slices", [(2, 4), (1, 2)])
@@ -225,19 +226,36 @@ def test_executor_record_holds_spans_and_dispatch_counters(executor, n,
     rec = executor.submit(task).result()
     passes = 1 if slices == 4 else 2
     names = [s[0] for s in rec.spans]
-    assert names == ["executor.queue", "executor.run"] + list(PHASES) * passes
+    # spans are kept in the order they end: the head inside decode.enqueue
+    assert names == ["executor.queue", "executor.run"] + [
+        PHASES[0], PHASES[1], HEAD, PHASES[2], PHASES[3]] * passes
     (_, q0, q1), (_, r0, r1) = rec.spans[:2]
-    children = rec.spans[2:]
+    children = [s for s in rec.spans[2:] if s[0] in PHASES]
     assert q0 <= q1 <= r0                      # queued, then taken up
     # the children lie inside the run, in order, without overlap
     edges = [t for _, t0, t1 in children for t in (t0, t1)]
     assert edges == sorted(edges) and r0 <= edges[0] and edges[-1] <= r1
-    # ... and are the timed phases: their sums are the record's times
+    # the head opens its pass's decode.enqueue and lies inside it
+    enq = [s for s in rec.spans if s[0] == PHASES[2]]
+    heads = [s for s in rec.spans if s[0] == HEAD]
+    for (_, e0, e1), (_, h0, h1) in zip(enq, heads, strict=True):
+        assert e0 <= h0 <= h1 <= e1
+    # wall_ms is timed from continuous points (a pass's prefill dispatch,
+    # its prefill sync's end, its decode sync's end); the phases tile it,
+    # so their sums agree with it up to the spans' own enter and exit
     ms = lambda pre: sum(t1 - t0 for n_, t0, t1 in children
                          if n_.startswith(pre)) / 1e6
     assert ms("executor.") == pytest.approx(rec.wall_ms, abs=1.0)
-    assert ms("executor.prefill.") == pytest.approx(rec.prefill_ms, abs=1e-6)
-    assert ms("executor.decode.") == pytest.approx(rec.decode_ms, abs=1e-6)
+    assert ms("executor.prefill.") == pytest.approx(rec.prefill_ms, abs=1.0)
+    assert ms("executor.decode.") == pytest.approx(rec.decode_ms, abs=1.0)
+    assert rec.wall_ms == pytest.approx(rec.prefill_ms + rec.decode_ms)
+    pre0 = [t0 for n_, t0, _ in children if n_ == PHASES[0]]
+    pre1 = [t1 for n_, _, t1 in children if n_ == PHASES[1]]
+    dec1 = [t1 for n_, _, t1 in children if n_ == PHASES[3]]
+    assert rec.prefill_ms == pytest.approx(
+        sum(b - a for a, b in zip(pre0, pre1)) / 1e6)
+    assert rec.decode_ms == pytest.approx(
+        sum(b - a for a, b in zip(pre1, dec1)) / 1e6)
     assert rec.tid == task.tid
     assert rec.uids == tuple(j.inst.uid for j in task.jobs)
     assert rec.dispatch_ms == task.dispatch_ms == 100.0
