@@ -96,7 +96,8 @@ def _fd_dyn_kernel(t_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     (SMEM) instead of a Python int baked into the trace, so one compiled
     executable serves every decode step — the per-token recompile the
     static kernel would force is exactly what the serving executor's
-    compile cache must never see."""
+    compile cache must never see.  ``t_ref[1]`` is the row offset of
+    the layer's batch in a stacked cache, read only by the index maps."""
     _fd_step(t_ref[0], q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
              **kw)
 
@@ -109,13 +110,16 @@ def _scratch(h: int, d: int) -> list:
             pltpu.VMEM((h, 1), jnp.float32)]
 
 
-def flash_decode_dynamic(q, k_cache, v_cache, t, *, window=None,
-                         local_block=None, block_k=512, interpret=False):
+def flash_decode_dynamic(q, k_cache, v_cache, t, layer=None, *,
+                         window=None, local_block=None, block_k=512,
+                         interpret=False):
     """Like :func:`flash_decode`, but ``t`` is a traced int32 scalar
     delivered via scalar prefetch — jit once, decode every position.
 
-    q: (B, H, D); caches: (B, S, KV, D); t: int32 array (any 0/1-d shape).
-    Returns (B, H, D)."""
+    q: (B, H, D); caches: (B, S, KV, D), or with a traced ``layer`` the
+    stacked caches of every layer, (L·B, S, KV, D), of which the kernel
+    reads rows ``layer·B .. layer·B + B - 1`` in place (no slice of the
+    layer is made); t: int32 array (any 0/1-d shape).  Returns (B, H, D)."""
     b, h, d = q.shape
     s, kvh = k_cache.shape[1], k_cache.shape[2]
     n_rep = h // kvh
@@ -127,24 +131,29 @@ def flash_decode_dynamic(q, k_cache, v_cache, t, *, window=None,
         _fd_dyn_kernel, scale=scale, window=window, local_block=local_block,
         block_k=block_k, kv_len=s, n_rep=n_rep)
 
+    def kv_map(b_, j, t_):
+        return (t_[1] + b_, j, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, nk),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda b_, j, t_: (b_, 0, 0)),
-            pl.BlockSpec((1, block_k, kvh, d), lambda b_, j, t_: (b_, j, 0, 0)),
-            pl.BlockSpec((1, block_k, kvh, d), lambda b_, j, t_: (b_, j, 0, 0)),
+            pl.BlockSpec((1, block_k, kvh, d), kv_map),
+            pl.BlockSpec((1, block_k, kvh, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda b_, j, t_: (b_, 0, 0)),
         scratch_shapes=_scratch(h, d),
     )
-    t_arr = jnp.reshape(jnp.asarray(t, jnp.int32), (1,))
+    row0 = 0 if layer is None else layer * b
+    scalars = jnp.stack([jnp.reshape(jnp.asarray(t, jnp.int32), ()),
+                         jnp.asarray(row0, jnp.int32)])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
-    )(t_arr, q, k_cache, v_cache)
+    )(scalars, q, k_cache, v_cache)
 
 
 def flash_decode(q, k_cache, v_cache, *, t, window=None, local_block=None,

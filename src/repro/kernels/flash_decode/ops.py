@@ -22,12 +22,14 @@ def flash_decode(q, k_cache, v_cache, *, t, window=None, local_block=None,
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "local_block", "block_k"))
-def flash_decode_at(q, k_cache, v_cache, t, *, window=None, local_block=None,
-                    block_k=512):
+def flash_decode_at(q, k_cache, v_cache, t, layer=None, *, window=None,
+                    local_block=None, block_k=512):
     """``flash_decode`` with a *traced* position ``t`` (scalar prefetch):
     one compiled executable serves the whole decode loop — the variant
     the serving executor and the model decode path use, since a static
-    ``t`` would recompile every token."""
-    return _kernel_dyn(q, k_cache, v_cache, t, window=window,
+    ``t`` would recompile every token.  With a traced ``layer`` the
+    caches are every layer's, stacked as (L·B, S, KV, D), and the kernel
+    reads that layer's rows where they lie."""
+    return _kernel_dyn(q, k_cache, v_cache, t, layer, window=window,
                        local_block=local_block, block_k=block_k,
                        interpret=interpret_mode())

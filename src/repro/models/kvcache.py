@@ -8,7 +8,10 @@ Three cache layouts, chosen per architecture:
   * SSM state              (B, H, K, V) + token-shift states — RWKV6 / hymba.
 
 All per-layer caches are stacked on a leading layer axis so the layer loop is a
-single ``lax.scan`` with the cache as scanned-over xs/ys.
+single ``lax.scan``.  In decode each stack rides the scan's carry, not its
+xs/ys: a layer writes its one new row into the stack in place and reads its
+own rows where they lie, so no layer's cache is sliced out, written back or
+copied whole each step.
 """
 from __future__ import annotations
 

@@ -349,46 +349,50 @@ def _seq_shard_decode(cfg, opts, q, k_new, v_new, k_cache, v_cache, t, kind):
     return fn(q, k_new, v_new, k_cache, v_cache, t)
 
 
-def _attn_decode(cfg, w, x, k_cache, v_cache, t, kind, opts):
-    """One-token attention. x: (B,1,D). Returns (out, k_cache', v_cache')."""
+def _attn_decode(cfg, w, x, k_all, v_all, layer, t, kind, opts):
+    """One-token attention. x: (B,1,D); k_all/v_all: the caches of every
+    layer of this kind, (L, B, S, KV, D), of which this one is ``layer``
+    (traced).  Writes the new token's row in place and reads the layer
+    where it lies.  Returns (out, k_all', v_all')."""
     b = x.shape[0]
     q, k_new, v_new = _qkv(cfg, w, x, t[None] if t.ndim == 0 else t, opts,
                            mode="decode")
     if opts.seq_shard_decode and opts.mesh is not None:
-        o, k_cache, v_cache = _seq_shard_decode(
-            cfg, opts, q, k_new, v_new, k_cache, v_cache, t, kind)
+        o, k_c, v_c = _seq_shard_decode(
+            cfg, opts, q, k_new, v_new, k_all[layer], v_all[layer], t, kind)
+        k_all = k_all.at[layer].set(k_c)
+        v_all = v_all.at[layer].set(v_c)
         o = o.reshape(b, 1, cfg.n_heads * cfg.d_head)
-        return jnp.einsum("bsh,hd->bsd", o, w["wo"]), k_cache, v_cache
-    wsize = k_cache.shape[1]
-    if kind in ("full", "global"):
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new, t, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new, t, axis=1)
-        if opts.use_kernels:
-            # flash-decoding kernel with the position delivered via
-            # scalar prefetch: the same compiled executable serves every
-            # decode step (a static t would recompile per token, which
-            # the serving executor's compile cache must never see)
-            from repro.kernels.flash_decode import ops as fd_ops
-            o = fd_ops.flash_decode_at(q[:, 0], k_cache, v_cache, t)[:, None]
-        else:
-            o = L.decode_attention(q, k_cache, v_cache, length=t + 1)
+        return jnp.einsum("bsh,hd->bsd", o, w["wo"]), k_all, v_all
+    full = kind in ("full", "global")
+    slot = t if full else t % k_all.shape[2]
+    k_all = jax.lax.dynamic_update_slice(k_all, k_new[None], (layer, 0, slot, 0, 0))
+    v_all = jax.lax.dynamic_update_slice(v_all, v_new[None], (layer, 0, slot, 0, 0))
+    if full and opts.use_kernels:
+        # flash-decoding kernel with the position delivered via scalar
+        # prefetch, so the same compiled executable serves every decode
+        # step; it reads the layer's rows of the stacked cache in place
+        from repro.kernels.flash_decode import ops as fd_ops
+        stacked = (-1,) + k_all.shape[2:]
+        o = fd_ops.flash_decode_at(q[:, 0], k_all.reshape(stacked),
+                                   v_all.reshape(stacked), t, layer)[:, None]
+    elif full:
+        o = L.decode_attention(q, k_all[layer], v_all[layer], length=t + 1)
     else:
-        slot = t % wsize
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new, slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new, slot, axis=1)
         window = cfg.window if kind == "window" else None
         local = cfg.chunk_attn if kind == "local" else None
-        o = L.decode_ring_attention(q, k_cache, v_cache, t=t,
+        o = L.decode_ring_attention(q, k_all[layer], v_all[layer], t=t,
                                     window=window, local_block=local)
     o = o.reshape(b, 1, cfg.n_heads * cfg.d_head)
-    return jnp.einsum("bsh,hd->bsd", o, w["wo"]), k_cache, v_cache
+    return jnp.einsum("bsh,hd->bsd", o, w["wo"]), k_all, v_all
 
 
 def _sublayer(cfg, w, x, kind, opts, mode, cache_kv=None, t=None, q_offset=0):
-    """One transformer layer.  Returns (x, aux, new_kv)."""
+    """One transformer layer.  In decode, ``cache_kv`` is (k_all, v_all,
+    layer) as :func:`_attn_decode` takes them.  Returns (x, aux, new_kv)."""
     h = _norm(cfg, w, "ln1", x)
     if mode == "decode":
-        a, k_c, v_c = _attn_decode(cfg, w, h, cache_kv[0], cache_kv[1], t, kind, opts)
+        a, k_c, v_c = _attn_decode(cfg, w, h, *cache_kv, t, kind, opts)
         new_kv = (k_c, v_c)
     else:
         a, k, v = _attn_full_seq(cfg, w, h, kind, opts, q_offset)
@@ -517,49 +521,43 @@ def _fill_cache(cfg, ks, vs, s, opts, max_len=None):
 # ---------------------------------------------------------------------------
 def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens: Array,
                 opts: RunOptions = RunOptions()):
-    """tokens: (B, 1) int32.  Returns (logits (B, V), new_cache)."""
+    """tokens: (B, 1) int32.  Returns (logits (B, V), new_cache).
+
+    Each cache stack rides the layer scan's carry, viewed as (L, B, S, KV,
+    D) with the macro blocks' layers flattened: a sublayer writes its new
+    row in place, so no layer's cache is sliced out, written back or
+    copied whole."""
     n_macro, m, kinds = macro_shape(cfg)
     t = cache["t"]
     x = params["embed"][tokens[:, :1]].astype(opts.act_dtype)
-
     if cfg.global_every:
-        xs = (params["layers"], cache["k_local"], cache["v_local"],
-              cache["k_global"], cache["v_global"])
-
-        def body(x, scanned):
-            block_w, kl, vl, kg, vg = scanned
-            new_kl, new_vl, new_kg, new_vg = [], [], [], []
-            for j in range(m):
-                wj = {k: v[j] for k, v in block_w.items()}
-                if kinds[j] == "local":
-                    x, _, (nk, nv) = _sublayer(cfg, wj, x, "local", opts,
-                                               "decode", (kl[j], vl[j]), t)
-                    new_kl.append(nk); new_vl.append(nv)
-                else:
-                    x, _, (nk, nv) = _sublayer(cfg, wj, x, "global", opts,
-                                               "decode", (kg[0], vg[0]), t)
-                    new_kg.append(nk); new_vg.append(nv)
-            return x, (jnp.stack(new_kl), jnp.stack(new_vl),
-                       jnp.stack(new_kg), jnp.stack(new_vg))
-
-        x, (kl, vl, kg, vg) = jax.lax.scan(body, x, xs)
-        new_cache = {"t": t + 1, "k_local": kl, "v_local": vl,
-                     "k_global": kg, "v_global": vg}
+        pairs = (("k_local", "v_local"), ("k_global", "v_global"))
+        pair_of = [0] * (m - 1) + [1]        # each sublayer's cache pair
     else:
-        xs = (params["layers"], cache["k"], cache["v"])
+        pairs, pair_of = (("k", "v"),), [0]
+    per_block = [pair_of.count(g) for g in range(len(pairs))]
 
-        def body(x, scanned):
-            block_w, kc, vc = scanned
-            nks, nvs = [], []
-            for j in range(m):
-                wj = {k: v[j] for k, v in block_w.items()}
-                x, _, (nk, nv) = _sublayer(cfg, wj, x, kinds[j], opts,
-                                           "decode", (kc[j], vc[j]), t)
-                nks.append(nk); nvs.append(nv)
-            return x, (jnp.stack(nks), jnp.stack(nvs))
+    def body(carry, scanned):
+        x, kv = carry
+        block_w, i = scanned
+        kv = list(kv)
+        for j in range(m):
+            g = pair_of[j]
+            layer = i * per_block[g] + pair_of[:j].count(g)
+            wj = {k: v[j] for k, v in block_w.items()}
+            x, _, kv[g] = _sublayer(cfg, wj, x, kinds[j], opts, "decode",
+                                    (*kv[g], layer), t)
+        return (x, tuple(kv)), None
 
-        x, (ks, vs) = jax.lax.scan(body, x, xs)
-        new_cache = {"t": t + 1, "k": ks, "v": vs}
+    kv = tuple(tuple(cache[n].reshape((-1,) + cache[n].shape[2:]) for n in pair)
+               for pair in pairs)
+    if n_macro:        # a reduced stack may hold no whole macro block
+        (x, kv), _ = jax.lax.scan(body, (x, kv),
+                                  (params["layers"], jnp.arange(n_macro)))
+    new_cache = {"t": t + 1}
+    for pair, arrays in zip(pairs, kv):
+        new_cache.update({n: a.reshape(cache[n].shape)
+                          for n, a in zip(pair, arrays)})
 
     x = _norm(cfg, params, "final_norm", x)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]

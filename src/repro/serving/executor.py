@@ -25,8 +25,12 @@ Fast-path design, in order of importance:
   of a bucket share the bucket's executables (quota is a run-count, see
   below), so a quota change can never trigger a recompile either.
 * **Donated decode buffers** — the decode step donates the KV cache
-  (``donate_argnums``), so the hot loop updates the cache in place
-  instead of allocating a fresh one per token.
+  (``donate_argnums``), and the model's layer scan carries it, writing
+  each layer's one new row in place: no layer is sliced out or written
+  back, and no cache-sized buffer is allocated or copied per token.
+  ``decode_cache_copies`` counts, per bucket, the compiled decode
+  program's instructions that would move a cache-sized array anyway
+  (:func:`cache_copies`); it is 0.
 * **Async dispatch** — ``submit()`` enqueues onto a single-worker
   executor and returns a future immediately; the gateway/emulator
   thread never blocks on device completion.  ``drain()`` collects the
@@ -71,6 +75,7 @@ planner split the queue).
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Optional
@@ -112,6 +117,33 @@ class ExecRecord:
     dispatch_ms: float = 0.0    # control-plane time the task was dispatched
     waits_ms: tuple = ()        # per job: dispatch_ms - its ready time
     queued: int = 0             # jobs in the stage queue at dispatch
+
+
+# one HLO instruction: its name, result shape and opcode
+_HLO_OP = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[^\s=]+)\s*=\s*\w+"
+                     r"\[(?P<dims>[\d,]*)\]\S*\s+(?P<op>[\w-]+)\(")
+
+
+def cache_copies(hlo_text: str, cache) -> int:
+    """Instructions of a compiled program that make a cache-sized array
+    (last three dims those of a cache stack: S, KV, D) by a copy, a
+    dynamic slice (or a fusion named for one) or a fresh buffer
+    (``AllocateBuffer``): the per-layer slices, write-back stacks and
+    whole-cache copies that an in-place decode step does without."""
+    tails = {tuple(a.shape[-3:]) for a in jax.tree.leaves(cache)
+             if len(a.shape) >= 4}
+    n = 0
+    for line in hlo_text.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        dims = tuple(int(x) for x in m["dims"].split(",") if x)
+        if dims[-3:] not in tails:
+            continue
+        n += (m["op"] == "copy" or "dynamic-slice" in m["name"]
+              or m["op"] == "dynamic-slice"
+              or 'custom_call_target="AllocateBuffer"' in line)
+    return n
 
 
 def phase_ms(spans: list) -> tuple[float, float]:
@@ -171,6 +203,9 @@ class RealExecutor:
         # (stage, bucket) -> whether the compiled program calls a
         # compiled Pallas kernel (False: interpreted on the CPU)
         self.kernel_calls: dict[tuple, bool] = {}
+        # bucket -> cache-sized slices, copies and buffers the compiled
+        # decode program makes (see cache_copies); 0 when it runs in place
+        self.decode_cache_copies: dict[int, int] = {}
         self.compiles = 0            # actual XLA compilations performed
         self.warmup_compiles = 0     # ... of which during warmup()
         self.cache_hits = 0          # submit()-time cache hits
@@ -205,6 +240,8 @@ class RealExecutor:
         for stage, exe in (("prefill", prefill), ("decode", decode)):
             self.kernel_calls[(stage, bucket)] = \
                 "tpu_custom_call" in exe.as_text()
+        self.decode_cache_copies[bucket] = cache_copies(decode.as_text(),
+                                                        cache)
         return prefill, decode
 
     @property
@@ -388,6 +425,7 @@ class RealExecutor:
             "prompt_len": self.prompt_len,
             "gen_len": self.gen_len,
             "interpret": self.interpret,
+            "decode_cache_copies": dict(self.decode_cache_copies),
             "compiles": self.compiles,
             "warmup_compiles": self.warmup_compiles,
             "cache_hits": self.cache_hits,

@@ -63,6 +63,35 @@ def test_prefill_decode_matches_forward(arch, key):
         assert jnp.abs(lg - full[:, off + S + i]).max() < 1e-3 * scale
 
 
+@pytest.mark.parametrize("use_kernels", [True, False])
+def test_decode_writes_one_row_per_layer_in_place(use_kernels, key):
+    """After n decode steps the cache is the prefill cache of the longer
+    prompt: rows of the prompt untouched, each step's row holding its K/V,
+    rows past the last step still empty."""
+    import dataclasses
+    import numpy as np
+    cfg = reduced(get_config("internlm2_1_8b"))
+    m = get_model(cfg, dataclasses.replace(OPTS, use_kernels=use_kernels))
+    params = m.init(key)
+    S, n, max_len = 24, 4, 32
+    tokens = jax.random.randint(key, (2, S + n), 0, cfg.vocab, jnp.int32)
+    _, start = m.prefill(params, {"tokens": tokens[:, :S]}, max_len=max_len)
+    _, longer = m.prefill(params, {"tokens": tokens[:, :S + n]},
+                          max_len=max_len)
+    cache = jax.tree.map(jnp.copy, start)
+    for i in range(n):
+        _, cache = m.decode(params, cache, tokens[:, S + i:S + i + 1])
+    assert int(cache["t"]) == S + n
+    for name in ("k", "v"):
+        got = np.asarray(cache[name])
+        np.testing.assert_array_equal(got[..., :S, :, :],
+                                      np.asarray(start[name])[..., :S, :, :])
+        want = np.asarray(longer[name])[..., S:S + n, :, :]
+        np.testing.assert_allclose(got[..., S:S + n, :, :], want,
+                                   rtol=1e-4, atol=1e-4 * np.abs(want).max())
+        assert not got[..., S + n:, :, :].any()
+
+
 def test_moe_routing_flop_exact():
     """Capacity+gather MoE computes at most cf x active-expert slots."""
     from repro.models import moe

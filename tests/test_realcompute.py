@@ -89,6 +89,47 @@ def test_flash_decode_at_one_executable_for_all_t():
     assert flash_decode_at._cache_size() == before
 
 
+def _stacked_cache(key, n_layers, b, s=64, kvh=2, d=16):
+    """Every layer's K and V stacked as the decode scan carries them:
+    (L·B, S, KV, D), layer ``l`` at rows ``l·B .. l·B + B - 1``."""
+    ks = jax.random.split(key, 2)
+    return (_rand(ks[0], (n_layers * b, s, kvh, d)),
+            _rand(ks[1], (n_layers * b, s, kvh, d)))
+
+
+@pytest.mark.parametrize("b", [1, 4])
+@pytest.mark.parametrize("layer", [0, 2, 4])       # first, middle, last
+def test_flash_decode_at_layer_reads_its_rows_in_place(b, layer):
+    """With ``layer`` the kernel reads that layer's rows of the stacked
+    cache: the same numbers as the call on the layer's own slice."""
+    from repro.kernels.flash_decode.ops import flash_decode_at
+    n_layers, h, d = 5, 4, 16
+    q = _rand(jax.random.PRNGKey(3), (b, h, d))
+    kc, vc = _stacked_cache(jax.random.PRNGKey(4), n_layers, b)
+    rows = slice(layer * b, (layer + 1) * b)
+    for t in (0, 17, 63):
+        out = flash_decode_at(q, kc, vc, t, layer)
+        per_layer = flash_decode_at(q, kc[rows], vc[rows], t)
+        np.testing.assert_array_equal(out, per_layer)
+
+
+def test_flash_decode_at_one_executable_for_all_t_and_layers():
+    """The layer offset is scalar-prefetched like ``t``: one jit cache
+    entry serves every (t, layer) of the decode loop."""
+    from repro.kernels.flash_decode.ops import flash_decode_at
+    n_layers, b, h, d = 3, 2, 2, 8
+    q = _rand(jax.random.PRNGKey(5), (b, h, d))
+    kc, vc = _stacked_cache(jax.random.PRNGKey(6), n_layers, b, s=32,
+                            kvh=1, d=d)
+    layer = jnp.asarray(0, jnp.int32)
+    flash_decode_at(q, kc, vc, 0, layer)       # prime the jit cache
+    before = flash_decode_at._cache_size()
+    for t in (1, 7, 31):
+        for layer in range(n_layers):
+            flash_decode_at(q, kc, vc, t, jnp.asarray(layer, jnp.int32))
+    assert flash_decode_at._cache_size() == before
+
+
 # ---- ops-level parity: rwkv6 wkv6 -----------------------------------------
 
 def test_wkv6_ops_parity():
